@@ -417,7 +417,7 @@ def _bench_mesh_ring(smoke: bool):
     """
     import jax
     from jax import lax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.codegen.collectives import ring_psum
@@ -433,7 +433,7 @@ def _bench_mesh_ring(smoke: bool):
     def run_with(fn):
         f = shard_map(
             lambda xs: fn(xs[0]), mesh=mesh,
-            in_specs=P("data"), out_specs=P(), check_rep=False,
+            in_specs=P("data"), out_specs=P(), check_vma=False,
         )
         return f(x)
 

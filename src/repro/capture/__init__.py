@@ -20,7 +20,7 @@ optimized primitives.
 
 Layers:
 
-  ``harvest``   walk a jaxpr (recursing through scan/remat/pjit/...),
+  ``harvest``   walk a jaxpr (recursing through scan/remat/jit/...),
                 classify every ``dot_general`` into a ``ContractionSpec``
                 named by ``core.enumerate`` — so each site owns the same
                 plan-DB/autotune keys a hand-rewired op would — and report

@@ -4,7 +4,7 @@ The model zoo (``repro.models``) lowers its matmuls through ``jnp.dot`` /
 ``jnp.einsum``, i.e. through the ``dot_general`` primitive — not through
 ``repro.ops`` — so before this module only hand-rewired call sites owned
 plan-DB/autotune keys.  ``harvest_jaxpr`` walks a traced function (recursing
-into ``pjit``/``scan``/``remat``/``cond``/``while``/``custom_*`` sub-jaxprs),
+into ``jit``/``scan``/``remat``/``cond``/``while``/``custom_*`` sub-jaxprs),
 classifies each ``dot_general`` equation against the spec families of
 ``core.enumerate`` (the single home of contraction naming, so every harvested
 site owns the same plan-DB and autotune-cache keys a hand-rewired ``ops``
@@ -26,7 +26,7 @@ import json
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from ..core.enumerate import (
     ContractionSpec,
@@ -43,7 +43,7 @@ SUPPORTED_DTYPES = ("float32", "bfloat16")
 #: sub-jaxpr-carrying primitives the rewriter knows how to re-emit; sites
 #: inside any *other* jaxpr-carrying primitive are fallback by containment
 REWRITABLE_HOPS = frozenset({
-    "pjit", "closed_call", "core_call",
+    "jit", "closed_call", "core_call",
     "scan", "while", "cond",
     "remat", "remat2", "checkpoint",
     "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
@@ -416,7 +416,7 @@ def _match_attention(div_eqn, producers, consumers, live_out, *, interpret):
 
     causal = False
     if (
-        masked.primitive.name == "pjit"
+        masked.primitive.name == "jit"
         and str(masked.params.get("name")) in ("_where", "where")
         and len(masked.invars) == 3
     ):

@@ -18,7 +18,7 @@ inside them:
   ======================  ==============================================
   primitive               re-emission
   ======================  ==============================================
-  ``pjit`` / calls        inlined (the caller's ``jit`` re-fuses)
+  ``jit`` / calls         inlined (the caller's ``jit`` re-fuses)
   ``scan``                rebuilt with ``lax.scan`` over the rewritten body
   ``while``               rebuilt with ``lax.while_loop``
   ``cond``                rebuilt with ``lax.switch``
@@ -58,7 +58,7 @@ import os
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 from jax import lax
 
 from .harvest import CaptureReport, classify_dot_general, harvest_jaxpr
@@ -245,7 +245,7 @@ def _eval_jaxpr(
             else:
                 outs = _bind(eqn, invals)
 
-        elif name in ("pjit", "closed_call", "core_call"):
+        elif name in ("jit", "closed_call", "core_call"):
             inner = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
             outs = _eval_jaxpr(inner, invals, ctx)
 

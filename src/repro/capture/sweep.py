@@ -115,7 +115,7 @@ def sweep_captured(
     plan_db=None,
     beam_width: int = 4,
     topk: int = 2,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     measure: bool = True,
     repeats: int = 1,
     verbose: bool = False,

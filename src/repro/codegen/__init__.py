@@ -50,7 +50,12 @@ from .mesh_gen import (
     operand_partition_spec,
     output_partition_spec,
 )
-from .pallas_gen import CompiledKernel, cached_compile, compile_kernel
+from .pallas_gen import (
+    CompiledKernel,
+    cached_compile,
+    compile_kernel,
+    compiled_kernels,
+)
 from .plan import KernelPlan, build_plan
 from .schedules import (
     batched_matmul_schedule,
@@ -78,6 +83,7 @@ __all__ = [
     "chain_matmul_schedule",
     "compile",
     "compile_kernel",
+    "compiled_kernels",
     "default_cache",
     "default_schedule",
     "hardware_fingerprint",

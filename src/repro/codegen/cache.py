@@ -77,14 +77,11 @@ def spec_signature(spec: ContractionSpec) -> Dict[str, Any]:
 
 
 def hardware_fingerprint() -> str:
-    """backend + device kind; 'cpu/interpret' in the CPU container."""
-    try:
-        import jax
+    """backend + device kind, e.g. 'tpu/TPU v5 lite' or 'cpu/cpu'."""
+    import jax
 
-        dev = jax.devices()[0]
-        return f"{jax.default_backend()}/{getattr(dev, 'device_kind', '?')}"
-    except Exception:
-        return "unknown"
+    dev = jax.devices()[0]
+    return f"{jax.default_backend()}/{dev.device_kind}"
 
 
 def cache_key(

@@ -32,13 +32,6 @@ from jax import lax
 STRATEGIES = ("psum", "ring")
 
 
-def _axis_size(axis_name: str) -> int:
-    """lax.axis_size where available; psum(1) constant-folds on 0.4.37."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def _ring_perm(p: int):
     return [(i, (i + 1) % p) for i in range(p)]
 
@@ -54,7 +47,7 @@ def ring_psum(x: jax.Array, axis_name: str) -> jax.Array:
     tests alongside the even fast path).  ``p == 1`` is the cut path: no
     ring to run, the partial *is* the sum.
     """
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     if p == 1:
         return x  # cut path: a single shard needs no collective
     idx = lax.axis_index(axis_name)
@@ -117,7 +110,7 @@ def ring_gather_matmul(x_shard: jax.Array, w: jax.Array, axis_name: str):
     The explicit ring exposes the overlap to the scheduler; the naive form
     must finish the all-gather before the first flop.
     """
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
 
     def step(carry, _):

@@ -45,9 +45,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.cost import VMEM_LIMIT_BYTES
 from ..core.enumerate import ContractionSpec
 from ..core.schedule import Schedule
-from ..kernels._compat import CompilerParams as COMPILER_PARAMS_CLS
 from .plan import KernelPlan, build_plan
 
 #: large-but-finite score for masked positions — exp(MASK - m) underflows
@@ -148,8 +148,9 @@ def _attention_fn(
                 pltpu.VMEM((bh, bs), jnp.float32),
                 pltpu.VMEM((bh, bs, E), jnp.float32),
             ],
-            compiler_params=COMPILER_PARAMS_CLS(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES,
             ),
             interpret=interpret,
         )(*arrays)
@@ -250,8 +251,9 @@ def _grouped_row_fn(
             out_specs=pl.BlockSpec((N, bn), lambda j, g: (0, j)),
             out_shape=jax.ShapeDtypeStruct((N, OC), dt),
             scratch_shapes=[pltpu.VMEM((N, bn), jnp.float32)],
-            compiler_params=COMPILER_PARAMS_CLS(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES,
             ),
             interpret=interpret,
         )(x, w)
@@ -312,8 +314,9 @@ def _grouped_dw_fn(
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, K1, bn), lambda g, j: (g, 0, j)),
             out_shape=jax.ShapeDtypeStruct((G, K1, K2), dt),
-            compiler_params=COMPILER_PARAMS_CLS(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel"),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES,
             ),
             interpret=interpret,
         )(a, b)

@@ -21,7 +21,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Tuple
 
-from jax.experimental.shard_map import shard_map
+import jax
 from jax.sharding import PartitionSpec as P
 
 from .collectives import STRATEGIES, all_reduce
@@ -137,13 +137,13 @@ def bind_mesh(kernel, mesh, collective: str = "psum") -> MeshBoundKernel:
             out = epilogue.apply(out, vectors).astype(out_dtype)
         return out
 
-    wrapped = shard_map(
+    wrapped = jax.jit(jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=in_specs + (vec_spec,) * len(vec_names),
         out_specs=out_spec,
-        check_rep=False,
-    )
+        check_vma=False,
+    ))
 
     def call(*arrays, **vectors):
         missing = set(vec_names) - set(vectors)

@@ -32,9 +32,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.cost import VMEM_LIMIT_BYTES
 from ..core.enumerate import ContractionSpec
 from ..core.schedule import Schedule
-from ..kernels._compat import CompilerParams as COMPILER_PARAMS_CLS
 from .epilogue import Epilogue
 from .plan import KernelPlan, build_plan
 
@@ -267,8 +267,9 @@ class CompiledKernel:
                 out_specs=out_spec,
                 out_shape=jax.ShapeDtypeStruct(plan.out_shape(), out_dtype),
                 scratch_shapes=[pltpu.VMEM(plan.out_block(), acc_dtype)],
-                compiler_params=COMPILER_PARAMS_CLS(
+                compiler_params=pltpu.CompilerParams(
                     dimension_semantics=("parallel",) * len(grid),
+                    vmem_limit_bytes=VMEM_LIMIT_BYTES,
                 ),
                 interpret=self.interpret,
             )(*ops, *rows)
@@ -355,6 +356,13 @@ def compile_kernel(
 _KERNEL_MEMO: Dict[tuple, CompiledKernel] = {}
 
 
+def compiled_kernels() -> List[object]:
+    """Every kernel ``cached_compile`` has built in this process — the
+    kernels the ops dispatched (``CompiledKernel``, ``FusedKernel`` or
+    ``MeshBoundKernel``)."""
+    return list(_KERNEL_MEMO.values())
+
+
 def cached_compile(
     spec: ContractionSpec,
     schedule: Schedule,
@@ -370,8 +378,9 @@ def cached_compile(
 
     Hot-path entry for ``ops``/``launch``: repeated calls with the same
     contraction reuse one jitted kernel instead of re-tracing.  Mesh-bound
-    kernels key on the mesh's axes and device ids, so two distinct meshes
-    of the same shape get distinct shard_map closures.
+    kernels key on the mesh's axes and (for a concrete mesh) device ids,
+    so two distinct meshes of the same shape get distinct shard_map
+    closures.
     """
     import json
 
@@ -381,8 +390,11 @@ def cached_compile(
     if mesh is not None:
         mesh_key = (
             tuple(mesh.axis_names),
-            tuple(int(s) for s in mesh.devices.shape),
-            tuple(int(d.id) for d in mesh.devices.flat),
+            tuple(int(s) for s in mesh.axis_sizes),
+            # an abstract mesh (what jit tracing sees) runs on the devices
+            # of the concrete mesh jax.set_mesh activated
+            tuple(int(d.id) for d in mesh.devices.flat)
+            if isinstance(mesh, jax.sharding.Mesh) else (),
         )
     key = (
         json.dumps(spec_signature(spec), sort_keys=True),

@@ -19,7 +19,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..core.cost import TPU
+from ..core.cost import TPU, kernel_vmem_bytes
 from ..core.enumerate import ContractionSpec
 from ..core.schedule import Schedule
 from .cache import (
@@ -53,33 +53,26 @@ def _score(
     elem_bytes: int,
     hw: dict,
 ) -> Optional[float]:
-    """HBM traffic (elements) of the generated kernel, or None if > VMEM.
+    """HBM traffic (elements) of the generated kernel, or None if its VMEM
+    footprint (``core.cost.kernel_vmem_bytes``) exceeds the budget.
 
     Each operand is re-fetched once per grid block of every *output* index
-    it does not carry; reduce (seq) axes are VMEM-resident at full extent
-    in generated kernels, so they count fully toward the budget.
+    it does not carry.
     """
+    if kernel_vmem_bytes(spec, blocks, elem_bytes) > hw["vmem_bytes"]:
+        return None
     extents = spec.extents
     n_blocks = {
         i: extents[i] // blocks[i] for i in spec.output
     }
-    vmem = 0
     traffic = 0.0
     for name, axes in spec.operands.items():
-        block_elems = 1
-        for a in axes:
-            block_elems *= blocks[a] if a in spec.output else extents[a]
-        vmem += block_elems
         elems = math.prod(extents[a] for a in axes)
         trips = math.prod(
             n_blocks[i] for i in spec.output if i not in axes
         )
         traffic += elems * trips
-    out_block = math.prod(blocks[i] for i in spec.output)
-    vmem += 2 * out_block  # out tile + f32 accumulator
     traffic += math.prod(extents[i] for i in spec.output)
-    if vmem * elem_bytes > hw["vmem_bytes"]:
-        return None
     # MXU alignment nudges: innermost output axis wants multiples of lanes
     penalty = 1.0
     last = spec.output[-1]
@@ -149,8 +142,9 @@ def tune_schedule(
 
     Cache hit -> deserialize, no enumeration, no measurement.  Miss ->
     analytic search; if ``measure_with`` provides operand arrays the
-    analytic top-``keep`` are timed through the interpreter-mode generated
-    kernel before the winner is stored.
+    analytic top-``keep`` are timed through the generated kernel (the
+    Pallas interpreter off a TPU) before the winner is stored.  Raises
+    ``ValueError`` when no candidate fits the VMEM budget.
     """
     from ..obs import span
 
@@ -190,23 +184,25 @@ def tune_schedule(
                     if i not in spec.output
                 )
                 scored.append((s, steps, tuple(sorted(blocks.items()))))
-        if not scored:  # nothing fits VMEM: fall back to smallest blocks
-            blocks = {
-                i: (1 if i in spec.output else spec.extents[i])
-                for i in spec.indices
-            }
-            scored = [(math.inf, 0, tuple(sorted(blocks.items())))]
+        if not scored:
+            raise ValueError(
+                f"no block choice for {spec.name} {spec.extents} fits the "
+                f"{hw['vmem_bytes']} B VMEM budget"
+            )
         scored.sort()
         top = [dict(b) for _, _, b in scored[:keep]]
 
         best = top[0]
         if measure_with is not None and len(top) > 1:
+            from ..ops import default_interpret
             from .pallas_gen import compile_kernel
 
             timings = []
             for blocks in top:
                 sched = default_schedule(spec, blocks)
-                kern = compile_kernel(spec, sched, interpret=True)
+                kern = compile_kernel(
+                    spec, sched, interpret=default_interpret()
+                )
                 args = tuple(measure_with[n] for n in spec.operands)
                 t0 = time.perf_counter()
                 np.asarray(kern(*args))

@@ -17,7 +17,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .cost import TPU, cpu_cost, rank_variants, tpu_cost
+from .cost import TPU, cpu_cost, pipeline_vmem_bytes, rank_variants, tpu_cost
 from .enumerate import ContractionSpec, variant_orders
 from .execute import execute_variant
 
@@ -165,17 +165,23 @@ def choose_matmul_blocks(
     k: int,
     elem_bytes: int = 2,
     hw: dict = TPU,
-    double_buffer: bool = True,
 ) -> Tuple[int, int, int]:
     """(block_m, block_n, block_k) minimizing HBM traffic under VMEM.
 
     Napkin model (the TPU analogue of the paper's cache reasoning):
       traffic = M*K * (N/bn)  +  K*N * (M/bm)  +  M*N
-    so we maximize bm, bn subject to
-      (bm*bk + bk*bn + bm*bn) * elem * (2 if double_buffer) <= VMEM
+    so we maximize bm, bn subject to the k-streamed kernel's footprint —
+    double-buffered (bm, bk), (bk, bn) and (bm, bn) blocks plus the f32
+    (bm, bn) accumulator (``cost.pipeline_vmem_bytes``) — fitting VMEM,
     with every extent a multiple of the MXU tile where possible.
     """
-    budget = hw["vmem_bytes"] // (2 if double_buffer else 1) // elem_bytes
+
+    def fits(bm: int, bn: int, bk: int) -> bool:
+        return pipeline_vmem_bytes(
+            [bm * bk * elem_bytes, bk * bn * elem_bytes],
+            [bm * bn * elem_bytes],
+            bm * bn * 4,
+        ) <= hw["vmem_bytes"]
 
     def aligned(align: int, size: int, cap: int = 1024) -> List[int]:
         """Divisors of ``size`` that are pow2 multiples of ``align``."""
@@ -190,7 +196,7 @@ def choose_matmul_blocks(
     for bm in aligned(8, m):
         for bn in aligned(128, n):
             for bk in aligned(128, k):
-                if bm * bk + bk * bn + bm * bn > budget:
+                if not fits(bm, bn, bk):
                     continue
                 traffic = m * k * (n / bn) + k * n * (m / bm) + m * n
                 # prefer deeper k-blocks on ties (fewer grid steps)

@@ -137,15 +137,106 @@ def early_cut(
 # TPU flavour
 # ---------------------------------------------------------------------------
 
+#: Scoped VMEM (bytes) every Pallas kernel of this repo is compiled with
+#: (``vmem_limit_bytes``), and the budget ``kernel_vmem_bytes`` holds every
+#: block choice to (``codegen.tune``, ``search.beam``,
+#: ``core.autotune.choose_matmul_blocks``).  A TPU v5e core has 128 MiB of
+#: VMEM; without the explicit limit Mosaic allows a kernel only its 16 MiB
+#: default, and refuses the blocks the selectors picked.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
 #: v5e-like hardware model (see DESIGN.md §6)
 TPU = dict(
     peak_flops=197e12,  # bf16
     hbm_bw=819e9,
-    vmem_bytes=64 * 1024 * 1024,  # usable VMEM working budget
+    vmem_bytes=VMEM_LIMIT_BYTES,
     ici_bw=50e9,  # per link
     mxu=(128, 128),
     sublane=8,
 )
+
+
+def pipeline_vmem_bytes(in_blocks, out_blocks, scratch: int = 0) -> int:
+    """VMEM a Pallas TPU kernel holds, in bytes.
+
+    The pipeline double-buffers every input and output block (``in_blocks``
+    / ``out_blocks``, bytes each) so the next block's DMA overlaps this
+    step's compute; ``scratch`` (accumulators and the body's temporaries)
+    is held once.
+    """
+    return 2 * (sum(in_blocks) + sum(out_blocks)) + scratch
+
+
+def kernel_vmem_bytes(
+    spec: ContractionSpec,
+    blocks: Dict[str, int],
+    elem_bytes: int,
+    *,
+    extents: Dict[str, int] = None,
+    out_bytes: int = None,
+) -> int:
+    """VMEM of the generated kernel for ``spec`` under per-index ``blocks``.
+
+    ``blocks`` maps each root index to its grid block (map indices) or its
+    per-step chunk (reduce indices); omitted indices are whole.
+    ``extents`` are the per-shard extents (default: the root's).  Operands
+    are stored at ``elem_bytes``, the output at ``out_bytes`` (default: the
+    operands', or the 4-byte accumulator of a quantized spec).  Mirrors the
+    layouts ``codegen.pallas_gen`` and ``codegen.fused_gen`` build:
+
+    * contraction: an operand block holds its map indices at their block
+      and its reduce indices at full extent (the reduction is resident and
+      the body loops over chunks); scratch is the f32 accumulator, the
+      step's dot result and one f32 chunk of every operand;
+    * attention: Q, K and V blocks stream the KV axis through the grid;
+      scratch is the running max/sum, the accumulator, the score and
+      probability tiles and the f32 copies of the blocks;
+    * grouped matmul: the whole row set stays resident per column block.
+    """
+    f32 = 4
+    spec = spec.root()
+    ext = dict(extents or spec.extents)
+    if out_bytes is None:
+        out_bytes = f32 if getattr(spec, "quant", None) else elem_bytes
+    b = {i: int(blocks.get(i, ext[i])) for i in spec.indices}
+    kind = getattr(spec, "fused_kind", "")
+    if kind == "attention":
+        bh, bs, bt = b["h"], b["s"], b["t"]
+        ins = [bh * bs * ext["d"], bh * bt * ext["d"], bh * bt * ext["e"]]
+        out = bh * bs * ext["e"]
+        scratch = 2 * bh * bs + out + 2 * bh * bs * bt + sum(ins)
+        return pipeline_vmem_bytes(
+            [n * elem_bytes for n in ins], [out * out_bytes], scratch * f32
+        )
+    if kind == "grouped_matmul":
+        names = tuple(spec.operands)
+        if "g" in spec.output:  # dW: (n, o1) x (n, o2) -> (g, o1, o2)
+            _, o1, o2 = spec.output
+            n = ext[spec.operands[names[0]][0]]
+            ins = [n * ext[o1], n * b[o2]]
+            out = ext[o1] * b[o2]
+            scratch = sum(ins) + out
+        else:  # row mode: x (n, c) x w (g, c, oc) -> (n, oc)
+            n_ax, c_ax = spec.operands[names[0]]
+            oc = spec.output[1]
+            n, c = ext[n_ax], ext[c_ax]
+            ins = [n * c, c * b[oc]]
+            out = n * b[oc]
+            scratch = out + b[n_ax] * (c + b[oc])
+        return pipeline_vmem_bytes(
+            [m * elem_bytes for m in ins], [out * out_bytes], scratch * f32
+        )
+    ins, chunks = [], 0
+    for axes in spec.operands.values():
+        ins.append(math.prod(
+            b[a] if a in spec.output else ext[a] for a in axes
+        ))
+        chunks += math.prod(b[a] for a in axes)
+    out = math.prod(b[i] for i in spec.output)
+    return pipeline_vmem_bytes(
+        [n * elem_bytes for n in ins], [out * out_bytes],
+        (2 * out + chunks) * f32,
+    )
 
 
 def tpu_cost(

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
+from ...core.cost import VMEM_LIMIT_BYTES
 
 _ACTIVATIONS = {
     "relu": lambda z: jnp.maximum(z, 0.0),
@@ -87,8 +87,9 @@ def fused_dense_act_pallas(
         out_specs=pl.BlockSpec((block_b, block_k), lambda bi, ki, ii: (bi, ki)),
         out_shape=jax.ShapeDtypeStruct((b, k), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_b, block_k), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(x, w, row(beta), row(mean), row(var))

@@ -19,15 +19,9 @@ import jax.numpy as jnp
 
 from ...core.autotune import choose_matmul_blocks
 from ...core.enumerate import matmul_spec
+from ...ops import _use_pallas
 from .matmul import matmul_pallas
 from .ref import matmul_ref
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
 
 
 def _generated_matmul(a, b, block_m, block_n, block_k, interpret):
@@ -70,7 +64,7 @@ def matmul(
 ) -> jax.Array:
     m, k = a.shape
     _, n = b.shape
-    use_pallas = force_pallas or interpret or _on_tpu()
+    use_pallas = force_pallas or interpret or _use_pallas()
     if not use_pallas:
         return matmul_ref(a, b)
     if use_generated and block_m is None and block_n is None and block_k is None:

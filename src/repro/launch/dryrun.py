@@ -109,9 +109,7 @@ def run_cell(
         return rec
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()
-    from .mesh import set_mesh
-
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             bundle = train_bundle(mesh, cfg, shape)
         elif shape.kind == "prefill":

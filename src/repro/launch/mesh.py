@@ -15,19 +15,11 @@ config choice in launch.train.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5 has explicit axis types; 0.4.37 (pinned) does not
-    from jax.sharding import AxisType
 
-    def _mesh(shape, axes):
-        return jax.make_mesh(
-            shape, axes, axis_types=(AxisType.Auto,) * len(axes)
-        )
-
-except ImportError:
-
-    def _mesh(shape, axes):
-        return jax.make_mesh(shape, axes)
+def _mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -41,61 +33,48 @@ def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
     return _mesh(shape, axes)
 
 
+def hosted_mesh(mesh_shape):
+    """The ``--mesh`` shape ('2x2' or (2, 2)) over this process's devices.
+
+    Raises ``ValueError`` when the process has too few devices: a mesh
+    that cannot be hosted is an error, never a silent single-device run.
+    """
+    import math
+
+    from ..search.space import mesh_axis_names, parse_mesh_shape
+
+    if isinstance(mesh_shape, str):
+        mesh_shape = parse_mesh_shape(mesh_shape)
+    shape = tuple(int(s) for s in mesh_shape)
+    if math.prod(shape) > jax.device_count():
+        raise ValueError(
+            f"mesh {'x'.join(map(str, shape))} needs {math.prod(shape)} "
+            f"devices; {jax.device_count()} visible"
+        )
+    return make_debug_mesh(shape, mesh_axis_names(len(shape)))
+
+
 def batch_axes(mesh) -> tuple:
     """Mesh axes that carve the global batch (pod+data when present)."""
     names = mesh.axis_names
     return tuple(a for a in ("pod", "data") if a in names)
 
 
-# -- jax version compat shims -------------------------------------------------
-
-
-def set_mesh(mesh):
-    """Context manager activating ``mesh``: jax.set_mesh when it exists
-    (jax >= 0.6), else the Mesh object itself (the 0.4.x context API)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def axis_size(axis_name: str) -> int:
-    """lax.axis_size where available; psum(1) constant-folds on 0.4.37.
-
-    Single implementation lives with the collectives (codegen cannot
-    import launch without inverting layering); this is the launch-facing
-    name.
-    """
-    from ..codegen.collectives import _axis_size
-
-    return _axis_size(axis_name)
-
-
 def active_mesh():
-    """The mesh the current (trace) context is running under, or None.
+    """The mesh the current context runs under (``jax.set_mesh``), or None.
 
-    On 0.4.x this is the ``with mesh:`` context (``thread_resources``);
-    newer jax exposes ``jax.set_mesh``/abstract meshes — we try the
-    thread-resources path first because that is what ``set_mesh`` returns
-    on the pinned version.  ``ops._tuned_kernel`` consults this to decide
-    whether a mesh-qualified plan lookup applies.
+    Returns the abstract mesh, which is what is visible while ``jax.jit``
+    traces; ``codegen.bind_mesh`` shard_maps over it and the jitted
+    program runs on the concrete devices ``jax.set_mesh`` was given.
+    ``ops._tuned_kernel`` consults this to decide whether a
+    mesh-qualified plan lookup applies.
     """
-    try:
-        from jax.interpreters.pxla import thread_resources
-
-        m = thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    try:  # jax >= 0.6: an explicitly set global mesh
-        m = jax.sharding.get_mesh()  # type: ignore[attr-defined]
-        if m is not None and getattr(m, "size", 0) > 1:
-            return m
-    except Exception:
-        pass
-    return None
+    m = jax.sharding.get_abstract_mesh()
+    if m.empty or m.size <= 1:
+        return None
+    return m
 
 
 def mesh_shape_descriptor(mesh) -> str:
     """'2x4'-style descriptor of a mesh (the plan-key qualifier)."""
-    return "x".join(str(int(s)) for s in mesh.devices.shape)
+    return "x".join(str(int(s)) for s in mesh.axis_sizes)

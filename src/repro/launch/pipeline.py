@@ -32,9 +32,7 @@ def pipeline_apply(
 ):
     """Run inside shard_map.  Returns (M, mb, ...) outputs (on every member,
     via a final psum-style broadcast)."""
-    from .mesh import axis_size
-
-    p = axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     m = microbatches.shape[0]
     ticks = m + p - 1

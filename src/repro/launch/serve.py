@@ -40,6 +40,8 @@ from .. import obs
 from ..configs import get_config
 from ..models.api import get_api
 from ..obs import log
+from .compile_cache import enable_compile_cache
+from .mesh import hosted_mesh
 
 
 @dataclasses.dataclass
@@ -71,28 +73,14 @@ class BatchServer:
         # temporaries.
         self.quant = quant
         # --mesh AxB: sweeps below additionally persist mesh-qualified
-        # sharded ladders, and — when this replica can host the mesh —
-        # the serving steps trace under it so ops._tuned_kernel dispatches
-        # through the sharded generated kernels (codegen.bind_mesh).
+        # sharded ladders, and the serving steps trace under the mesh so
+        # ops._tuned_kernel dispatches through the sharded generated
+        # kernels (codegen.bind_mesh).
         self.mesh = None
         self.mesh_shape = None
         if mesh_shape:
-            from ..search import parse_mesh_shape
-            from .mesh import make_debug_mesh
-
-            self.mesh_shape = parse_mesh_shape(mesh_shape)
-            import math as _math
-
-            from ..search.space import mesh_axis_names
-
-            if _math.prod(self.mesh_shape) <= jax.device_count():
-                self.mesh = make_debug_mesh(
-                    self.mesh_shape, mesh_axis_names(len(self.mesh_shape))
-                )
-            else:
-                log.info("serve", f"--mesh {mesh_shape}: only "
-                         f"{jax.device_count()} device(s) visible — sweeping "
-                         f"mesh plans for the fleet, serving single-device")
+            self.mesh = hosted_mesh(mesh_shape)
+            self.mesh_shape = tuple(self.mesh.axis_sizes)
         # Whole-model capture: harvest the prefill + decode GEMM sets
         # (abstract trace — no allocation), sweep every harvested spec
         # into the ranked plan DB (fwd, plus derived bwd specs unless
@@ -124,7 +112,6 @@ class BatchServer:
             db = default_plan_db()
             n = _capture.sweep_captured(
                 list(points.values()), with_grads=search_grads, plan_db=db,
-                interpret=jax.default_backend() != "tpu",
                 mesh_shape=self.mesh_shape,
                 quant=self.quant,
             )
@@ -151,8 +138,6 @@ class BatchServer:
             db = default_plan_db()
             # bfloat16 to match warm_dense_cache: the plan key must equal
             # the one ops.dense derives from the serving activations.
-            # On a TPU replica measure the real kernels; the interpreter
-            # only stands in for the clock where there is no TPU.
             # search_grads: the plan DB is fleet-shared, so the same
             # warmup also sweeps each GEMM's derived backward specs
             # (repro.grad) and training replicas pick up searched
@@ -161,7 +146,6 @@ class BatchServer:
             n = search_gemm_plans(
                 search_gemms,
                 dtype=jnp.bfloat16,
-                interpret=jax.default_backend() != "tpu",
                 plan_db=db,
                 with_grads=search_grads,
                 mesh_shape=self.mesh_shape,
@@ -210,7 +194,7 @@ class BatchServer:
         self._prefill_fn = prefill_fn
 
     def _mesh_ctx(self):
-        """Trace/run context: the serving mesh when hosted, else a no-op.
+        """Trace/run context: the serving mesh, if any, else a no-op.
 
         Entering the mesh at call time is what lets ``ops._tuned_kernel``
         (consulted while jit traces the step) see an active mesh and pick
@@ -220,9 +204,7 @@ class BatchServer:
 
         if self.mesh is None:
             return contextlib.nullcontext()
-        from .mesh import set_mesh
-
-        return set_mesh(self.mesh)
+        return jax.set_mesh(self.mesh)
 
     def _prefill(self, tokens: np.ndarray, lengths=None):
         batch = {"tokens": jnp.asarray(tokens), **self.extra_batch}
@@ -397,9 +379,10 @@ def main():
         "--mesh", default=None, metavar="AxB",
         help="mesh shape ('2x4' = data x model) for the distributed "
              "schedule tier: --search-gemms/--capture sweeps also persist "
-             "mesh-qualified sharded ladders, and when this process can "
-             "host the mesh the serving steps trace under it so eligible "
-             "GEMMs dispatch through sharded generated kernels",
+             "mesh-qualified sharded ladders, and the serving steps trace "
+             "under it so eligible GEMMs dispatch through sharded "
+             "generated kernels.  An error when this process has fewer "
+             "devices than the mesh",
     )
     ap.add_argument(
         "--metrics-out", default=None, metavar="FILE",
@@ -433,6 +416,12 @@ def main():
              "kernels",
     )
     args = ap.parse_args()
+    if args.mesh:
+        try:
+            hosted_mesh(args.mesh)
+        except ValueError as e:
+            ap.error(f"--mesh: {e}")
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

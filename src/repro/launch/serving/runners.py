@@ -34,7 +34,6 @@ def _sweep(phase: str, shapes, *, with_grads: bool, mesh_shape=None) -> int:
     n = search_gemm_plans(
         shapes,
         dtype=jnp.bfloat16,
-        interpret=jax.default_backend() != "tpu",
         plan_db=db,
         with_grads=with_grads,
         mesh_shape=mesh_shape,

@@ -108,7 +108,6 @@ def make_train_step(
     lr_schedule: Optional[Callable] = None,
     microbatch: int = 1,
     capture: Optional[bool] = None,
-    mesh=None,
 ):
     """Loss + grad + optimizer update for one (micro)batch.
 
@@ -126,14 +125,12 @@ def make_train_step(
     through the same plan-DB pipeline, fwd and bwd.  Ineligible sites run
     untouched, so this is a strict superset of the uncaptured step.
 
-    ``mesh`` activates that mesh for the step body at trace time, so
-    ``ops._tuned_kernel`` consults the mesh-shape-qualified plan keys a
-    ``--mesh`` sweep persisted and eligible GEMMs dispatch through the
-    sharded generated kernels (``codegen.bind_mesh``).  Callers that
-    already trace under ``with set_mesh(mesh)`` (``train_bundle`` users)
-    get the same behaviour without passing it.
+    Trace the step under ``with jax.set_mesh(mesh)`` (outside the jit,
+    which is the only place JAX accepts it) and ``ops._tuned_kernel``
+    consults the mesh-shape-qualified plan keys a ``--mesh`` sweep
+    persisted, so eligible GEMMs dispatch through the sharded generated
+    kernels (``codegen.bind_mesh``).
     """
-    import contextlib
     import os
 
     api = get_api(cfg)
@@ -143,24 +140,13 @@ def make_train_step(
     if capture:
         from .. import capture as _capture
 
-        loss_inner = _capture.optimize(
+        loss_fn = _capture.optimize(
             base_loss, label=f"{cfg.arch_id}:train_step"
         )
     else:
-        loss_inner = base_loss
-
-    def _mesh_ctx():
-        if mesh is None:
-            return contextlib.nullcontext()
-        from .mesh import set_mesh
-
-        return set_mesh(mesh)
+        loss_fn = base_loss
 
     def train_step(params, opt_state, batch):
-        def loss_fn(p, b):
-            with _mesh_ctx():  # nullcontext when no mesh was given
-                return loss_inner(p, b)
-
         if microbatch > 1:
             def split(x):
                 return x.reshape(microbatch, x.shape[0] // microbatch,
@@ -229,7 +215,7 @@ def train_bundle(
     )
 
     step = make_train_step(cfg, opt_cfg, microbatch=microbatch,
-                           capture=capture, mesh=mesh)
+                           capture=capture)
     metrics_shard = {
         "grad_norm": NamedSharding(mesh, P()),
         "clip_scale": NamedSharding(mesh, P()),

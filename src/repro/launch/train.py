@@ -35,6 +35,7 @@ from ..models.api import get_api
 from ..optim import AdamWConfig, warmup_cosine
 from ..optim import adamw as optim
 from ..runtime.fault import FaultTolerantLoop, LoopConfig
+from .compile_cache import enable_compile_cache
 from .steps import make_train_step
 
 
@@ -135,6 +136,7 @@ def main():
                          "ones through the plan-DB pipeline "
                          "(repro.capture; also $REPRO_CAPTURE=1)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

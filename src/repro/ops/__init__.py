@@ -51,17 +51,20 @@ import numpy as np
 
 
 def _use_pallas() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    return jax.default_backend() == "tpu"
+
+
+def default_interpret() -> bool:
+    """The ``interpret`` the backend implies: real kernels on a TPU, the
+    Pallas interpreter everywhere else."""
+    return not _use_pallas()
 
 
 def _mesh_plan_kernel(spec, dtype, *, epilogue=None, interpret=False):
     """Sharded generated kernel from a mesh-qualified plan, or None.
 
-    When the calling context runs under a device mesh (``launch.mesh
-    .set_mesh`` / ``with mesh:`` — checked at trace time), the plan DB is
+    When the calling context runs under a device mesh (``jax.set_mesh``
+    — checked at trace time), the plan DB is
     consulted under the mesh-shape-qualified key ('2x4'-style,
     ``search.plandb.plan_key(mesh=...)``) for the best rung that actually
     distributes (``best_sharded_entry`` — under a live mesh the operands
@@ -86,8 +89,7 @@ def _mesh_plan_kernel(spec, dtype, *, epilogue=None, interpret=False):
     if sched is None:
         return None
     axes = schedule_mesh_axes(sched)
-    shape = dict(zip(mesh.axis_names, mesh.devices.shape))
-    if any(shape.get(a) != n for a, n in axes.items()):
+    if any(mesh.shape.get(a) != n for a, n in axes.items()):
         return None
     return codegen.cached_compile(
         spec, sched, epilogue=epilogue, interpret=interpret,
@@ -115,47 +117,29 @@ def _tuned_kernel(spec, dtype, *, epilogue=None, out_dtype=None,
     autotune cache).
     """
     from .. import codegen
+    from ..search import active_phase, default_plan_db
 
-    # PlanDB.best_schedule already degrades corrupt/stale entries to a
-    # miss; the catch here is for genuine breakage in the search package,
-    # which must not take down serving — but must not be silent either.
+    kern = _mesh_plan_kernel(
+        spec, dtype, epilogue=epilogue, interpret=interpret
+    )
+    if kern is not None:
+        return kern
+    # PlanDB.best_schedule degrades a corrupt or stale entry to a miss;
+    # anything else it raises is a fault and propagates
     schedule = None
-    try:
-        from ..search import active_phase, default_plan_db
-
-        kern = _mesh_plan_kernel(
-            spec, dtype, epilogue=epilogue, interpret=interpret
+    phase = active_phase()
+    if phase is not None:
+        schedule = default_plan_db().best_schedule(
+            spec, np.dtype(dtype), phase=phase
         )
-        if kern is not None:
-            return kern
-        phase = active_phase()
-        if phase is not None:
-            schedule = default_plan_db().best_schedule(
-                spec, np.dtype(dtype), phase=phase
-            )
-        if schedule is None:
-            schedule = default_plan_db().best_schedule(spec, np.dtype(dtype))
-    except Exception as e:
-        global _plan_db_warned
-        if not _plan_db_warned:
-            _plan_db_warned = True
-            import warnings
-
-            warnings.warn(
-                f"search plan DB unavailable ({type(e).__name__}: {e}); "
-                f"falling back to codegen.tune_schedule for all ops",
-                RuntimeWarning,
-                stacklevel=2,
-            )
+    if schedule is None:
+        schedule = default_plan_db().best_schedule(spec, np.dtype(dtype))
     if schedule is None:
         schedule = codegen.tune_schedule(spec, dtype=np.dtype(dtype))
     return codegen.cached_compile(
         spec, schedule, epilogue=epilogue, out_dtype=out_dtype,
         interpret=interpret,
     )
-
-
-_plan_db_warned = False
 
 
 def warm_dense_cache(shapes, dtype=jnp.bfloat16) -> int:

@@ -178,9 +178,9 @@ def _analyze_computation(lines: List[str]) -> CompStats:
                 contract = 0
                 op_bytes = 0.0
                 if opm:
-                    # one entry per operand token, positional: jax<=0.4.x
-                    # prints inline types (``f32[8,16]{1,0} %name``),
-                    # newer HLO just ``%name`` (sigil optional) — resolve
+                    # one entry per operand token, positional: a token
+                    # may carry its type inline (``f32[8,16]{1,0} %name``)
+                    # or be just ``%name`` (sigil optional) — resolve
                     # the type if present, else the symbol table
                     shapes = []
                     for tok in _split_operands(opm.group(1)):

@@ -12,8 +12,8 @@ scores variants analytically, ``codegen`` compiles a hand-picked Schedule.
     beam.beam_search                 analytic roofline prune (sound bound
       │                              cut + configurable-width beam trim)
       ▼
-    measure.measure_schedules        top-K lowered via codegen, timed under
-      │                              the autotune harness (interpret on CPU)
+    measure.measure_schedules        top-K lowered via codegen, timed on the
+      │                              chip (the interpreter off a TPU)
       ▼
     plandb.PlanDB                    ranked plans persisted next to the
                                      autotune cache; ops.dense asks here
@@ -30,8 +30,10 @@ specs of ``repro.grad``, so training's cotangent GEMMs are searched too.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from .. import obs
@@ -174,7 +176,7 @@ def search_schedule(
     elem_bytes: Optional[int] = None,
     hw: dict = TPU,
     measure: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     repeats: int = 2,
     arrays: Optional[Dict[str, np.ndarray]] = None,
     include_default: bool = True,
@@ -185,9 +187,11 @@ def search_schedule(
 ) -> SearchResult:
     """The end-to-end pipeline: enumerate -> prune -> measure -> persist.
 
-    Returns the ranked ladder best-first.  When ``measure`` is on, the
-    ranking is by measured seconds and — because ``include_default`` puts
-    the un-searched ``codegen.default_schedule`` into the measured set —
+    Returns the ranked ladder best-first.  Every rung fits the VMEM
+    budget its kernel is compiled with; a spec where none does raises
+    ``ValueError``.  When ``measure`` is on, the ranking is by measured
+    seconds and — because ``include_default`` puts the un-searched
+    ``codegen.default_schedule`` into the measured set wherever it fits —
     the winner is by construction never slower than the default on the
     measurement harness used.
 
@@ -199,10 +203,11 @@ def search_schedule(
     rides through measurement so the searched sharded winner is by
     construction never slower than it.  Sharded candidates are measured
     through ``codegen.bind_mesh`` over the visible devices (force a CPU
-    mesh with ``--xla_force_host_platform_device_count``); when the
-    process cannot host the mesh they keep their analytic rank behind the
-    measured single-device plans.  The ladder persists under the
-    mesh-qualified plan key (``matmul@mesh=2x4``-style).
+    mesh with ``--xla_force_host_platform_device_count``); a measured
+    mesh search in a process that cannot host the mesh raises
+    ``ValueError`` (``measure=False`` ranks the ladder analytically
+    without devices).  The ladder persists under the mesh-qualified plan
+    key (``matmul@mesh=2x4``-style).
 
     ``plan_db`` (or pass ``default_plan_db()``) persists the ladder;
     ``use_cached_plan`` short-circuits a repeated search of the same
@@ -289,12 +294,16 @@ def search_schedule(
 
         base_sched = default_schedule(spec)
         base_dict = _sched_dict(base_sched)
-        if not any(_sched_dict(p.schedule) == base_dict for p in plans):
-            est = estimate(
-                spec, spec.indices,
-                {i: spec.extents[i] for i in spec.indices},
-                elem_bytes=elem_bytes, hw=hw,
-            )
+        hit = [p for p in plans if _sched_dict(p.schedule) == base_dict]
+        for p in hit:
+            p.source = "default"
+        est = estimate(
+            spec, spec.indices,
+            {i: spec.extents[i] for i in spec.indices},
+            elem_bytes=elem_bytes, hw=hw,
+        )
+        # the unblocked kernel is a baseline only where it would compile
+        if not hit and est.fits_vmem:
             plans.append(
                 RankedPlan(
                     schedule=base_sched,
@@ -305,10 +314,6 @@ def search_schedule(
                     explain=_explain_of(est),
                 )
             )
-        else:
-            for p in plans:
-                if _sched_dict(p.schedule) == base_dict:
-                    p.source = "default"
 
     # mesh searches also measure the NAIVE lowering of the best sharded
     # subdivision — same mesh assignment, plain psum, no inner blocking —
@@ -343,52 +348,48 @@ def search_schedule(
                     elem_bytes=elem_bytes, hw=hw,
                     mesh=naive_mesh, collective="psum",
                 )
-                plans.append(
-                    RankedPlan(
-                        schedule=naive_sched,
-                        score=est.score,
-                        lower_bound=est.lower_bound,
-                        fits_vmem=est.fits_vmem,
-                        source="mesh-naive",
-                        collective="psum",
-                        explain=_explain_of(est),
+                if est.fits_vmem:
+                    plans.append(
+                        RankedPlan(
+                            schedule=naive_sched,
+                            score=est.score,
+                            lower_bound=est.lower_bound,
+                            fits_vmem=est.fits_vmem,
+                            source="mesh-naive",
+                            collective="psum",
+                            explain=_explain_of(est),
+                        )
                     )
-                )
 
-    measured_plans: List[RankedPlan] = []
-    if measure and plans:
+    if not plans:
+        raise ValueError(
+            f"no schedule for {spec.name} {spec.extents} fits the "
+            f"{hw['vmem_bytes']} B VMEM budget"
+        )
+    if measure:
         sharded = [p for p in plans if p.sharded]
         mesh = mesh_for_schedules([p.schedule for p in sharded])
         if mesh is None and sharded:
-            # process cannot host the mesh: measure the single-device
-            # candidates, keep sharded ones on their analytic rank
-            measured_plans = [p for p in plans if not p.sharded]
-        else:
-            measured_plans = list(plans)
-        if measured_plans:
-            with obs.span(
-                "search.measure", spec=spec.name, n=len(measured_plans)
-            ):
-                ms = measure_schedules(
-                    spec, [p.schedule for p in measured_plans],
-                    arrays=arrays, dtype=dt, interpret=interpret,
-                    repeats=repeats, mesh=mesh,
-                    collectives=[p.collective for p in measured_plans],
-                )
-            for p, m in zip(measured_plans, ms):
-                p.measured_s = m.seconds
-                p.max_err = m.max_err
-            stats.measured += len(ms)
-            obs.counter("search.measured").inc(len(ms))
-        plans.sort(
-            key=lambda p: (
-                p.measured_s is None,
-                p.measured_s if p.measured_s is not None else p.score,
-                p.score,
+            raise ValueError(
+                f"measuring the {mesh_desc} mesh ladder needs "
+                f"{math.prod(mesh_shape)} devices; {jax.device_count()} "
+                f"visible (measure=False ranks it analytically)"
             )
-        )
+        with obs.span("search.measure", spec=spec.name, n=len(plans)):
+            ms = measure_schedules(
+                spec, [p.schedule for p in plans],
+                arrays=arrays, dtype=dt, interpret=interpret,
+                repeats=repeats, mesh=mesh,
+                collectives=[p.collective for p in plans],
+            )
+        for p, m in zip(plans, ms):
+            p.measured_s = m.seconds
+            p.max_err = m.max_err
+        stats.measured += len(ms)
+        obs.counter("search.measured").inc(len(ms))
+        plans.sort(key=lambda p: (p.measured_s, p.score))
     else:
-        plans.sort(key=lambda p: (not p.fits_vmem, p.score))
+        plans.sort(key=lambda p: p.score)
 
     result = SearchResult(
         spec=spec, dtype=str(dt), ranked=plans, stats=stats,
@@ -517,14 +518,7 @@ def best_dtype_tier(results: Dict[str, SearchResult]) -> str:
     """
     if not results:
         raise ValueError("no tiers searched")
-    return min(
-        results,
-        key=lambda t: (
-            not results[t].best.fits_vmem,
-            results[t].best.score,
-            t,
-        ),
-    )
+    return min(results, key=lambda t: (results[t].best.score, t))
 
 
 def search_gemm_plans(
@@ -533,7 +527,7 @@ def search_gemm_plans(
     dtype=np.float32,
     beam_width: int = 8,
     topk: int = 3,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     measure: bool = True,
     plan_db: Optional[PlanDB] = None,
     with_grads: bool = False,

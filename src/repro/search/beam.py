@@ -7,13 +7,16 @@ rule, structured as a beam search:
   state     = (loop order, block choice for a prefix of the root indices)
   extension = pick the next index's block/chunk from ``space.block_choices``
   score     = pessimistic analytic step time (roofline max(compute, HBM
-              traffic) x alignment/VMEM penalties) with unassigned indices
+              traffic) x alignment penalties) with unassigned indices
               defaulted to whole-extent blocks
   bound     = the same roofline WITHOUT penalties — a true lower bound on
               the score of every completion of the state, because leaving
               an index whole minimizes trips for every operand
 
-Two prune mechanisms, kept separate because they have different guarantees:
+A complete candidate whose kernel does not fit the VMEM budget it is
+compiled with (``core.cost.kernel_vmem_bytes``) is dropped: the chip's
+compiler would refuse it.  Two prune mechanisms, kept separate because
+they have different guarantees:
 
   * **bound cut** (sound): a state is dropped when its lower bound already
     exceeds the best *complete* candidate's score — no completion can win.
@@ -42,7 +45,7 @@ import dataclasses
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from ..core.cost import TPU
+from ..core.cost import TPU, kernel_vmem_bytes
 from ..core.enumerate import ContractionSpec
 from .space import (
     Candidate,
@@ -67,6 +70,26 @@ class CostEstimate:
     seq_steps: int        # tie-break: fewer fori_loop steps win
     comm_s: float = 0.0   # exposed collective time (mesh-sharded reductions)
     shards: int = 1       # devices the candidate spreads over
+
+
+def fits_vmem(
+    spec: ContractionSpec,
+    blocks: Dict[str, int],
+    elem_bytes: int,
+    *,
+    hw: dict = TPU,
+    extents: Optional[Dict[str, int]] = None,
+) -> bool:
+    """Whether the generated kernel for ``blocks`` fits ``hw``'s VMEM
+    budget, at the spec's storage precisions."""
+    from ..roofline.analysis import quant_byte_model
+
+    op_b, out_b = quant_byte_model(
+        getattr(spec.root(), "quant", None), elem_bytes
+    )
+    return kernel_vmem_bytes(
+        spec, blocks, op_b, extents=extents, out_bytes=out_b
+    ) <= hw["vmem_bytes"]
 
 
 def estimate(
@@ -105,21 +128,13 @@ def estimate(
     for _, n in mesh.values():
         shards *= n
     n_blocks = {i: extents[i] // blocks[i] for i in spec.output}
-    vmem = 0
     traffic = 0.0
     for name, axes in spec.operands.items():
-        block_elems = 1
-        for a in axes:
-            # reduce axes are VMEM-resident at full extent in generated
-            # kernels (codegen.plan); only map blocking shrinks the block
-            block_elems *= blocks[a] if a in spec.output else extents[a]
-        vmem += block_elems
         elems = math.prod(extents[a] for a in axes)
         trips = math.prod(
             n_blocks[i] for i in spec.output if i not in axes
         )
         traffic += elems * trips
-    out_block = math.prod(blocks[i] for i in spec.output)
     out_elems = math.prod(extents[i] for i in spec.output)
 
     # quantized specs stream operands at storage precision (1 byte) but
@@ -129,13 +144,11 @@ def estimate(
     quant = getattr(spec, "quant", None)
     if quant is None:
         out_elem_bytes = elem_bytes
-        vmem_bytes = (vmem + 2 * out_block) * elem_bytes
         hbm_s = (traffic + out_elems) * elem_bytes / hw["hbm_bw"]
     else:
         from ..roofline.analysis import quant_byte_model
 
         op_b, out_elem_bytes = quant_byte_model(quant, elem_bytes)
-        vmem_bytes = vmem * op_b + 2 * out_block * out_elem_bytes
         hbm_s = (
             traffic * op_b + out_elems * out_elem_bytes
         ) / hw["hbm_bw"]
@@ -181,7 +194,7 @@ def estimate(
         )
 
     lower = max(hbm_s, compute_s, comm_s)
-    fits = vmem_bytes <= hw["vmem_bytes"]
+    fits = fits_vmem(spec, blocks, elem_bytes, hw=hw, extents=extents)
 
     decided = assigned if assigned is not None else frozenset(spec.indices)
     penalty = 1.0
@@ -200,8 +213,6 @@ def estimate(
     ]
     if grid and blocks.get(last, extents[last]) < extents[last] and grid[-1] != last:
         penalty *= 1.05
-    if not fits and decided == frozenset(spec.indices):
-        penalty *= 8.0  # would spill on real hardware
     seq_steps = sum(
         extents[i] // blocks[i] for i in spec.indices if i not in spec.output
     )
@@ -309,8 +320,10 @@ def beam_search(
 ) -> Tuple[List[ScoredCandidate], SearchStats]:
     """Enumerate-and-cut: returns the analytic top-``topk`` candidates.
 
-    The survivors are ranked best-first by (fits-VMEM, score, seq steps);
-    measurement of the survivors is ``measure.measure_schedules``'s job.
+    Only candidates whose kernel fits the VMEM budget
+    (``core.cost.kernel_vmem_bytes``) are returned — possibly none.  The
+    survivors are ranked best-first by (score, seq steps); measurement of
+    the survivors is ``measure.measure_schedules``'s job.
 
     ``bound_slack`` widens the sound cut: a state is dropped only when its
     lower bound exceeds ``slack x`` the best complete score, so candidates
@@ -359,6 +372,8 @@ def beam_search(
             g = _greedy_complete(
                 spec, order, var_choices[vi], elem_bytes, hw, v
             )
+            if not g.cost.fits_vmem:
+                continue
             if best_complete is None or g.sort_key() < best_complete.sort_key():
                 best_complete = g
             if v.assignment and (
@@ -397,6 +412,12 @@ def beam_search(
                 )
                 stats.considered += 1
                 sc = ScoredCandidate(cand, est)
+                if not complete_stage and not fits_vmem(
+                    spec,
+                    {**{i: min(c) for i, c in var_choices[vi].items()}, **nb},
+                    elem_bytes, hw=hw, extents=var_defaults[vi],
+                ):
+                    continue  # not even the smallest completion fits
                 if (
                     best_complete is not None
                     and not complete_stage
@@ -410,6 +431,8 @@ def beam_search(
                     )
                     continue
                 if complete_stage:
+                    if not est.fits_vmem:
+                        continue  # the chip's compiler would refuse it
                     if (
                         best_complete is None
                         or sc.sort_key() < best_complete.sort_key()

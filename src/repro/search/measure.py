@@ -2,9 +2,9 @@
 
 The paper measures *every* variant; after the analytic cut only the beam's
 top-K reach this stage.  Each survivor is lowered with ``codegen.compile``
-(the same path ``ops.dense`` uses) and timed; ``interpret=True`` runs the
-Pallas interpreter so the loop closes on CPU-only machines — on a TPU the
-same call times the real kernel.
+(the same path ``ops.dense`` uses) and timed.  ``interpret`` defaults to
+what the backend implies (``ops.default_interpret``): on a TPU the real
+kernel is timed, elsewhere the Pallas interpreter closes the loop.
 
 Schedules with ``mesh:*`` levels are lowered through ``codegen.bind_mesh``
 over a real device mesh: on a multi-chip host that is the hardware mesh,
@@ -16,7 +16,8 @@ from the visible devices, or returns None when the process cannot host it
 single-device ones are timed.
 
 Timing uses min-over-repeats after a warmup call (compilation is excluded),
-mirroring ``benchmarks.common.timeit``.
+each repeat ending in ``block_until_ready`` so it times the kernel and not
+a copy of its output to the host.
 """
 
 from __future__ import annotations
@@ -166,9 +167,12 @@ def einsum_reference(
             out[o : o + s_g] = vals[xname][o : o + s_g] @ wg
             o += s_g
         return out
+    # optimize=True contracts through BLAS: the plain einsum loop takes
+    # half a minute at a 512x4096x12288 projection
     return np.einsum(
         einsum_formula(spec),
         *(np.asarray(arrays[n], np.float64) for n in spec.operands),
+        optimize=True,
     )
 
 
@@ -178,7 +182,7 @@ def measure_schedules(
     *,
     arrays: Optional[Dict[str, np.ndarray]] = None,
     dtype=np.float32,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     repeats: int = 2,
     check: bool = True,
     tol: Optional[float] = None,
@@ -202,10 +206,15 @@ def measure_schedules(
     operands stay global arrays either way, so the oracle check is
     identical for sharded and single-device candidates.
     """
+    import jax
     import jax.numpy as jnp
+    from jax.sharding import NamedSharding
 
-    from ..codegen import cached_compile
+    from ..codegen import cached_compile, operand_partition_spec
+    from ..ops import default_interpret
 
+    if interpret is None:
+        interpret = default_interpret()
     spec = spec.root()
     quantized = np.dtype(dtype).itemsize == 1
     if tol is None:
@@ -240,7 +249,17 @@ def measure_schedules(
             mesh=mesh if sharded else None,
             collective=coll,
         )
-        result = np.asarray(kern(*jarrs))  # warmup (compile + first run)
+        args = jarrs
+        if sharded:
+            # each shard where the shard_map reads it: operands left on one
+            # device would be copied out on every call, and the copy timed
+            args = tuple(
+                jax.device_put(a, NamedSharding(
+                    mesh, operand_partition_spec(kern.plan, n)
+                ))
+                for a, n in zip(jarrs, spec.operands)
+            )
+        result = np.asarray(kern(*args))  # warmup (compile + first run)
         err = None
         if check:
             err = float(np.abs(result - ref).max() / max(np.abs(ref).max(), 1e-30))
@@ -252,7 +271,7 @@ def measure_schedules(
         best = float("inf")
         for _ in range(max(repeats, 1)):
             t0 = time.perf_counter()
-            np.asarray(kern(*jarrs))
+            jax.block_until_ready(kern(*args))
             best = min(best, time.perf_counter() - t0)
         out.append(Measurement(schedule=sched, seconds=best, max_err=err))
     return out

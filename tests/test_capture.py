@@ -418,7 +418,7 @@ def test_fallback_names_nearest_blocking_ancestor(monkeypatch):
 
     monkeypatch.setattr(
         hmod, "REWRITABLE_HOPS",
-        frozenset({"pjit", "closed_call", "core_call"}),
+        frozenset({"jit", "closed_call", "core_call"}),
     )
     w = _aligned(6, 128, 128)
 

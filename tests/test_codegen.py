@@ -315,3 +315,45 @@ def test_core_tune_cache_skips_remeasurement(tmp_path, monkeypatch):
     r3 = at.tune(spec, {"j": [8]}, measure_with=arrays, cache=cache2)
     assert calls["n"] == measured_once
     assert [tv.order for tv in r3] == [tv.order for tv in r1]
+
+
+# -- no silent fallbacks ------------------------------------------------------
+
+
+def test_tune_schedule_raises_when_no_block_fits():
+    """No block choice within the VMEM budget is an error, not a kernel of
+    all-1 output blocks that nobody would choose."""
+    from repro.core.cost import TPU
+
+    with pytest.raises(ValueError, match="VMEM budget"):
+        codegen.tune_schedule(
+            matmul_spec(256, 1024, 512), hw=dict(TPU, vmem_bytes=64 * 1024),
+            use_default_cache=False,
+        )
+
+
+def test_backend_errors_propagate(monkeypatch):
+    """A backend that fails to initialise is not taken for "not a TPU"."""
+    from repro import ops
+
+    def broken():
+        raise RuntimeError("backend failed to initialise")
+
+    monkeypatch.setattr(jax, "default_backend", broken)
+    with pytest.raises(RuntimeError, match="failed to initialise"):
+        ops._use_pallas()
+
+
+def test_plan_db_failure_propagates(monkeypatch):
+    """A plan DB that cannot be opened fails the op instead of warning once
+    and tuning analytically from then on."""
+    import repro.search as search
+    from repro import ops
+
+    def broken():
+        raise OSError("plan DB unreadable")
+
+    monkeypatch.setattr(search, "default_plan_db", broken)
+    x = jnp.ones((128, 128), jnp.float32)
+    with pytest.raises(OSError, match="unreadable"):
+        ops.dense(x, x, interpret=True)

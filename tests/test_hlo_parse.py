@@ -27,8 +27,6 @@ def test_scan_flops_multiplied_by_trip_count():
     assert out["dot_flops"] == pytest.approx(expect, rel=0.01)
     # XLA's own analysis counts the body once — our reason for existing
     xla_cost = comp.cost_analysis()
-    if isinstance(xla_cost, list):  # jax 0.4.x returns [dict]
-        xla_cost = xla_cost[0] if xla_cost else {}
     assert xla_cost["flops"] < expect / (L / 2)
 
 

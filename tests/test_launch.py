@@ -89,15 +89,12 @@ def test_debug_mesh_train_bundle_compiles(forced_devices):
         cfg = get_config("qwen3-8b").smoke()
         mesh = make_debug_mesh((2, 2), ("data", "model"))
         shape = ShapeConfig("tiny", 32, 8, "train")
-        from repro.launch.mesh import set_mesh
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             b = train_bundle(mesh, cfg, shape)
             compiled = jax.jit(
                 b.fn, out_shardings=b.out_shardings
             ).lower(*b.in_shapes).compile()
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):  # jax 0.4.x returns [dict]
-            cost = cost[0] if cost else {}
         print("FLOPS", cost.get("flops", 0))
         print("OK")
     """)
@@ -115,8 +112,7 @@ def test_debug_mesh_serve_bundle_compiles(forced_devices):
         cfg = get_config("granite-34b").smoke()   # MQA decode path
         mesh = make_debug_mesh((2, 2), ("data", "model"))
         shape = ShapeConfig("tinydecode", 64, 8, "decode")
-        from repro.launch.mesh import set_mesh
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             b = serve_bundle(mesh, cfg, shape)
             compiled = jax.jit(
                 b.fn, out_shardings=b.out_shardings
@@ -142,8 +138,7 @@ def test_train_step_runs_on_mesh_and_loss_decreases(forced_devices):
         cfg = get_config("deepseek-7b").smoke()
         api = get_api(cfg)
         mesh = make_debug_mesh((2, 2), ("data", "model"))
-        from repro.launch.mesh import set_mesh
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params, _ = api.init(cfg, jax.random.key(0))
             ocfg = AdamWConfig(lr=1e-2, moments_dtype="float32")
             opt = optim.init(params, ocfg)
@@ -168,7 +163,7 @@ def test_hierarchical_compressed_psum(forced_devices):
     out = forced_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.launch.mesh import make_debug_mesh
         from repro.optim.compress import hierarchical_psum
 
@@ -183,7 +178,7 @@ def test_hierarchical_compressed_psum(forced_devices):
 
         g = shard_map(
             f, mesh=mesh, in_specs=P(("pod", "data")), out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
         got = np.asarray(g(x))
         want = np.asarray(x).sum(axis=0, keepdims=True).repeat(1, 0)
@@ -208,7 +203,7 @@ def test_ring_collective_matmul_property(forced_devices, devices):
         import jax, jax.numpy as jnp, numpy as np
         from jax import lax
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.launch.mesh import make_debug_mesh
         from repro.codegen.collectives import ring_psum
         from repro.launch.overlap import naive_gather_matmul, ring_gather_matmul
@@ -232,12 +227,12 @@ def test_ring_collective_matmul_property(forced_devices, devices):
             ring = shard_map(
                 lambda xs, ws: ring_gather_matmul(xs, ws, "model"),
                 mesh=mesh, in_specs=(P("model", None), P()),
-                out_specs=P(), check_rep=False,
+                out_specs=P(), check_vma=False,
             )
             naive = shard_map(
                 lambda xs, ws: naive_gather_matmul(xs, ws, "model"),
                 mesh=mesh, in_specs=(P("model", None), P()),
-                out_specs=P(), check_rep=False,
+                out_specs=P(), check_vma=False,
             )
             got, want = np.asarray(ring(x, w)), np.asarray(naive(x, w))
             ref = np.asarray(x) @ np.asarray(w)
@@ -258,11 +253,11 @@ def test_ring_collective_matmul_property(forced_devices, devices):
             )
             rp = shard_map(
                 lambda v: ring_psum(v[0], "model"), mesh=mesh,
-                in_specs=P("model"), out_specs=P(), check_rep=False,
+                in_specs=P("model"), out_specs=P(), check_vma=False,
             )
             pp = shard_map(
                 lambda v: lax.psum(v[0], "model"), mesh=mesh,
-                in_specs=P("model"), out_specs=P(), check_rep=False,
+                in_specs=P("model"), out_specs=P(), check_vma=False,
             )
             got_r, want_r = np.asarray(rp(y)), np.asarray(pp(y))
             oracle = np.asarray(y).sum(0)
@@ -278,7 +273,7 @@ def test_pipeline_parallelism_over_pod_axis(forced_devices):
     out = forced_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.launch.mesh import make_debug_mesh
         from repro.launch.pipeline import bubble_fraction, pipeline_apply
 
@@ -297,7 +292,7 @@ def test_pipeline_parallelism_over_pod_axis(forced_devices):
             mesh=mesh,
             in_specs=(P("pipe", None, None), P()),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
         got = np.asarray(piped(Ws, xs))
 
@@ -309,3 +304,74 @@ def test_pipeline_parallelism_over_pod_axis(forced_devices):
         print("OK")
     """, devices=4)
     assert "OK" in out
+
+
+# -- entry-point set-up: the compile cache and --mesh -------------------------
+
+
+@pytest.fixture
+def restore_compile_cache():
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs")
+    was = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in was.items():
+        jax.config.update(n, v)
+    cc.reset_cache()
+
+
+def test_compile_cache_honours_the_env_var(
+    monkeypatch, tmp_path, restore_compile_cache
+):
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+
+
+def test_compile_cache_falls_back_to_a_fixed_repo_dir(
+    monkeypatch, restore_compile_cache
+):
+    """Without the variable the cache is ``.jax_cache/`` at the repository
+    root on every call: a path derived from a pid, a temporary name or the
+    time would never be found again."""
+    import os
+
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(root, ".jax_cache")
+    assert enable_compile_cache() == want
+    assert enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+
+
+def test_mesh_with_too_few_devices_is_an_error(monkeypatch, capsys):
+    """``--mesh`` that the process cannot host fails; it never serves on a
+    single device instead."""
+    import sys
+
+    import jax
+
+    from repro.launch import serve
+    from repro.launch.mesh import hosted_mesh
+
+    n = jax.device_count()
+    with pytest.raises(ValueError, match=f"needs {2 * (n + 1)} devices"):
+        hosted_mesh((2, n + 1))
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--arch", "qwen3-8b", "--smoke", "--mesh", f"2x{n + 1}",
+    ])
+    with pytest.raises(SystemExit) as exit_:
+        serve.main()
+    assert exit_.value.code == 2
+    assert f"needs {2 * (n + 1)} devices" in capsys.readouterr().err

@@ -234,23 +234,20 @@ def test_mesh_plan_keys_are_qualified_and_disjoint(tmp_path):
 
 
 def test_sharded_plans_rank_behind_measured_without_devices(tmp_path):
-    """Single-device process + mesh search: sharded candidates cannot be
-    measured, so they keep analytic scores and rank behind the measured
-    single-device plans instead of erroring."""
+    """Single-device process + measured mesh search: the sharded
+    candidates cannot be measured, and that is an error — not a ladder
+    whose sharded rungs silently keep their analytic rank.  Nothing is
+    persisted."""
     if jax.device_count() >= 8:
         pytest.skip("process has a real mesh; covered by the matrix test")
     spec = matmul_spec(64, 64, 64)
-    res = search_schedule(
-        spec, beam_width=4, topk=3, measure=True, interpret=True,
-        plan_db=PlanDB(str(tmp_path / "plans.json")), mesh_shape=(2, 4),
-    )
-    assert any(p.sharded for p in res.ranked)
-    for p in res.ranked:
-        if p.sharded:
-            assert p.measured_s is None
-        if p.measured_s is not None:
-            assert not p.sharded
-    assert res.best.measured_s is not None  # a measured plan still wins
+    db = PlanDB(str(tmp_path / "plans.json"))
+    with pytest.raises(ValueError, match="needs 8 devices"):
+        search_schedule(
+            spec, beam_width=4, topk=3, measure=True, interpret=True,
+            plan_db=db, mesh_shape=(2, 4),
+        )
+    assert db.best_schedule(spec, np.float32, mesh="2x4") is None
 
 
 # ---------------------------------------------------------------------------
@@ -394,7 +391,7 @@ def test_mesh_swept_model_serves_and_trains_sharded(forced_devices, tmp_path):
         from repro import capture, ops
         from repro.codegen import MeshBoundKernel
         from repro.core.enumerate import matmul_spec
-        from repro.launch.mesh import make_debug_mesh, set_mesh
+        from repro.launch.mesh import make_debug_mesh
         from repro.search import default_plan_db, search_schedule_with_grads
 
         M = D = F = 128  # the dense predicate's 128-alignment floor
@@ -415,7 +412,7 @@ def test_mesh_swept_model_serves_and_trains_sharded(forced_devices, tmp_path):
 
         # the lookup ops performs must now return a sharded kernel
         from repro.ops import _mesh_plan_kernel
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             kern = _mesh_plan_kernel(spec, np.float32, interpret=True)
         assert isinstance(kern, MeshBoundKernel), type(kern)
         assert any(
@@ -427,7 +424,7 @@ def test_mesh_swept_model_serves_and_trains_sharded(forced_devices, tmp_path):
 
         base_l, (base_gx, base_gw) = jax.value_and_grad(
             loss, argnums=(0, 1))(x, w)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             mesh_l, (mesh_gx, mesh_gw) = jax.value_and_grad(
                 loss, argnums=(0, 1))(x, w)
         np.testing.assert_allclose(
@@ -444,7 +441,7 @@ def test_mesh_swept_model_serves_and_trains_sharded(forced_devices, tmp_path):
 
         captured = capture.optimize(step, interpret=True)
         want = float(step(x, w))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             got = float(captured(x, w))
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
         print("OK")
@@ -452,4 +449,45 @@ def test_mesh_swept_model_serves_and_trains_sharded(forced_devices, tmp_path):
         "REPRO_PLAN_DB": str(tmp_path / "plans.json"),
         "REPRO_AUTOTUNE_CACHE": str(tmp_path / "autotune.json"),
     })
+    assert "OK" in out
+
+
+def test_sharded_candidates_are_timed_on_their_shards(forced_devices):
+    """A sharded candidate is timed with each operand already split over
+    the mesh as its shard_map reads it — not from one device, which would
+    time the copy out to every shard on each call."""
+    out = forced_devices("""
+        import jax
+        import numpy as np
+        from jax.sharding import NamedSharding
+
+        import repro.codegen as codegen
+        from repro.core.enumerate import matmul_spec
+        from repro.search import measure_schedules, search_schedule
+
+        spec = matmul_spec(128, 4096, 128)
+        res = search_schedule(spec, mesh_shape=(2, 2), measure=False)
+        sched = next(p.schedule for p in res.ranked if p.sharded)
+        seen = []
+        real = codegen.cached_compile
+
+        def spy(*a, **kw):
+            kern = real(*a, **kw)
+
+            def call(*args):
+                seen.append([x.sharding for x in args])
+                return kern(*args)
+
+            call.plan = kern.plan
+            return call if kw.get("mesh") is not None else kern
+
+        codegen.cached_compile = spy
+        measure_schedules(spec, [sched], interpret=True, repeats=2)
+        assert len(seen) == 3, len(seen)   # warm-up + 2 repeats
+        for shardings in seen:
+            for s in shardings:
+                assert isinstance(s, NamedSharding), s
+                assert len(s.device_set) == 4, s
+        print("OK")
+    """, devices=4)
     assert "OK" in out

@@ -260,3 +260,67 @@ def test_measured_search_winner_not_slower_than_default(tmp_path):
     assert base is not None and base.measured_s is not None
     assert res.best.measured_s <= base.measured_s
     assert res.stats.measured == len(res.ranked)
+
+
+# ---------------------------------------------------------------------------
+# VMEM budget and the measurement backend
+# ---------------------------------------------------------------------------
+
+
+def test_beam_returns_only_candidates_that_fit_vmem(tmp_path):
+    """A candidate whose kernel exceeds the VMEM it is compiled with would
+    be refused by the chip's compiler: it is dropped, not ranked last —
+    the unblocked default baseline included."""
+    from repro.core.cost import kernel_vmem_bytes
+
+    spec = matmul_spec(256, 1024, 512)
+    hw = dict(TPU, vmem_bytes=4 * 2**20)
+    assert kernel_vmem_bytes(spec, {}, 4) > hw["vmem_bytes"]
+    ranked, _ = beam_search(spec, beam_width=8, topk=8, hw=hw)
+    assert ranked
+    for sc in ranked:
+        assert sc.cost.fits_vmem
+        assert kernel_vmem_bytes(spec, sc.candidate.block_dict, 4) <= (
+            hw["vmem_bytes"]
+        )
+    res = search_schedule(
+        spec, beam_width=8, topk=8, hw=hw, measure=False,
+        plan_db=PlanDB(str(tmp_path / "plans.json")),
+    )
+    assert res.ranked and all(p.fits_vmem for p in res.ranked)
+    assert all(p.source != "default" for p in res.ranked)
+
+
+def test_search_refuses_a_spec_nothing_fits(tmp_path):
+    spec = matmul_spec(256, 1024, 512)
+    hw = dict(TPU, vmem_bytes=64 * 1024)
+    assert beam_search(spec, hw=hw)[0] == []
+    db = PlanDB(str(tmp_path / "plans.json"))
+    with pytest.raises(ValueError, match="VMEM budget"):
+        search_schedule(spec, hw=hw, measure=False, plan_db=db)
+    assert db.best_schedule(spec, np.float32) is None
+
+
+def test_interpret_default_follows_the_backend(monkeypatch):
+    """Unless told otherwise, measurement runs the real kernel on a TPU
+    and the Pallas interpreter elsewhere — a search on the chip that does
+    not pass ``interpret`` never times the interpreter."""
+    import repro.codegen as codegen
+    from repro import ops
+
+    assert ops.default_interpret() == (jax.default_backend() != "tpu")
+    seen = []
+    real = codegen.cached_compile
+
+    def spy(*args, **kw):
+        seen.append(kw["interpret"])
+        return real(*args, **{**kw, "interpret": True})  # no chip here
+
+    monkeypatch.setattr(codegen, "cached_compile", spy)
+    spec = matmul_spec(64, 64, 64)
+    search_schedule(spec, beam_width=2, topk=1, repeats=1)
+    assert seen and set(seen) == {ops.default_interpret()}
+    seen.clear()
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)  # as on a TPU
+    search_schedule(spec, beam_width=2, topk=1, repeats=1)
+    assert seen and set(seen) == {False}

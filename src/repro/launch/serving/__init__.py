@@ -17,15 +17,24 @@ Layers, bottom to top:
 * :mod:`.gateway` / :mod:`.trace` — drive a seeded multi-tenant Poisson
   trace through either engine with per-request observability.
 
+Importing the tier binds ``jax.profiler.TraceAnnotation`` to ``repro.obs``:
+while a profile is being taken, every obs span also lands on the
+profile's host plane, on the device planes' clock.
+
 ``launch.serve --engine continuous`` is the CLI entry point;
 ``benchmarks/serve_bench.py`` gates continuous >= fixed throughput.
 """
 
+import jax
+
+from ... import obs
 from .engine import ContinuousEngine, FixedEngine
 from .gateway import Gateway
 from .paged import PagePool, pool_init
 from .scheduler import Scheduler, ServeRequest
 from .trace import synthetic_trace
+
+obs.bind_annotation(jax.profiler.TraceAnnotation)
 
 __all__ = [
     "ContinuousEngine",

@@ -13,6 +13,14 @@ fixed-slot server behind the same ``run()`` interface.
 4. runs ONE decode step across all lanes (phase ``decode``, fixed
    shapes, compiled once) and emits one token per live request.
 
+Spans, in the order they run: ``serve.prefill`` (``rid``; children
+``serve.prefill.dispatch`` and ``serve.prefill.wait``) per admission, then
+per decode step ``serve.decode.prepare`` (block table, lengths, tokens),
+``serve.decode.step`` (children ``serve.decode.dispatch``, the jitted call,
+and ``serve.decode.wait``, the blocking copy of the tokens to the host) and
+``serve.decode.emit`` (emit, finish, free pages); ``host.gc`` around each
+collection of Python's oldest generation.
+
 Requests therefore join the decode batch the step after their prefill
 completes and leave it — freeing pages immediately — the step they
 finish; short and long requests share lanes without rounding every batch
@@ -111,6 +119,8 @@ class ContinuousEngine:
     ) -> Dict:
         latency = obs.histogram("serve.request_latency_s")
         ttft = obs.histogram("serve.ttft_s")
+        served = obs.counter("serve.requests")
+        tokens = obs.counter("serve.tokens")
         pending = collections.deque(
             sorted(requests, key=lambda r: (r.arrival_s, r.rid))
         )
@@ -125,7 +135,7 @@ class ContinuousEngine:
             else:
                 req.state = "finished"
             latency.observe(req.t_done - req.t_submit)
-            obs.counter("serve.requests").inc()
+            served.inc()
             obs.complete_event(
                 "serve.request", req.t_submit, req.t_done - req.t_submit,
                 rid=req.rid, tenant=req.tenant, prompt_len=len(req.prompt),
@@ -138,7 +148,7 @@ class ContinuousEngine:
                 req.t_first = time.perf_counter()
                 ttft.observe(req.t_first - req.t_submit)
             st["prefill_tokens" if from_prefill else "decode_tokens"] += 1
-            obs.counter("serve.tokens").inc()
+            tokens.inc()
             if (len(req.out_tokens) >= req.max_new
                     or (eos_id is not None and tok == eos_id)):
                 finish(req)
@@ -153,8 +163,8 @@ class ContinuousEngine:
                 return
             self.sched.submit(req)
 
-        with obs.span("serve.engine", engine="continuous",
-                      requests=len(requests)):
+        with obs.gc_spans(), obs.span("serve.engine", engine="continuous",
+                                      requests=len(requests)):
             while pending or self.sched.queue or self.sched.running:
                 now = time.perf_counter() - t0
                 while pending and pending[0].arrival_s <= now:
@@ -164,42 +174,48 @@ class ContinuousEngine:
 
                 for req in self.sched.admit():
                     tp = time.perf_counter()
-                    tok, self.pools = self.prefill(
-                        self.params, self.pools, req.context_tokens,
-                        req.pages,
-                    )
+                    # its start is the request's admission-to-service stamp
+                    with obs.span("serve.prefill", rid=req.rid,
+                                  tokens=len(req.context_tokens),
+                                  padded=len(req.pages) * self.page_size):
+                        tok, self.pools = self.prefill(
+                            self.params, self.pools, req.context_tokens,
+                            req.pages,
+                        )
                     st["prefill_s"] += time.perf_counter() - tp
                     emit(req, tok, from_prefill=True)
 
                 if not self.sched.running:
                     continue
-                pre = self.sched.grow()
-                st["preemptions"] += len(pre)
-                for _ in pre:
-                    obs.counter("serve.preempted").inc()
+                st["preemptions"] += len(self.sched.grow())
                 if not self.sched.running:
                     continue
 
-                bt = np.zeros((self.lanes, self.max_pages), np.int32)
-                lens = np.zeros((self.lanes,), np.int32)
-                toks = np.zeros((self.lanes,), np.int32)
-                for lane, req in self.sched.running.items():
-                    bt[lane, :len(req.pages)] = req.pages
-                    # the last emitted token's KV is not cached yet — the
-                    # step about to run writes it at position ctx_len - 1
-                    lens[lane] = req.ctx_len - 1
-                    toks[lane] = req.out_tokens[-1]
+                step = st["decode_steps"]
+                with obs.span("serve.decode.prepare", step=step):
+                    bt = np.zeros((self.lanes, self.max_pages), np.int32)
+                    lens = np.zeros((self.lanes,), np.int32)
+                    toks = np.zeros((self.lanes,), np.int32)
+                    for lane, req in self.sched.running.items():
+                        bt[lane, :len(req.pages)] = req.pages
+                        # the last emitted token's KV is not cached yet —
+                        # the step about to run writes it at ctx_len - 1
+                        lens[lane] = req.ctx_len - 1
+                        toks[lane] = req.out_tokens[-1]
                 td = time.perf_counter()
-                with obs.span("serve.decode.step", step=st["decode_steps"],
+                with obs.span("serve.decode.step", step=step,
                               live=len(self.sched.running)):
-                    next_tok, self.pools = self.decode(
-                        self.params, self.pools, bt, lens, toks
-                    )
-                    next_host = np.asarray(next_tok)
+                    with obs.span("serve.decode.dispatch", step=step):
+                        next_tok, self.pools = self.decode(
+                            self.params, self.pools, bt, lens, toks
+                        )
+                    with obs.span("serve.decode.wait", step=step):
+                        next_host = np.asarray(next_tok)
                 st["decode_s"] += time.perf_counter() - td
                 st["decode_steps"] += 1
-                for lane, req in list(self.sched.running.items()):
-                    emit(req, int(next_host[lane]), from_prefill=False)
+                with obs.span("serve.decode.emit", step=step):
+                    for lane, req in list(self.sched.running.items()):
+                        emit(req, int(next_host[lane]), from_prefill=False)
 
         st["tokens"] = st["prefill_tokens"] + st["decode_tokens"]
         st["tok_per_s"] = st["decode_tokens"] / max(st["decode_s"], 1e-9)

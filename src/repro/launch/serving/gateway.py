@@ -28,8 +28,7 @@ class Gateway:
     def run(
         self, trace: List[ServeRequest], *, eos_id: Optional[int] = None
     ) -> Dict:
-        with obs.span("serve.gateway", requests=len(trace)):
-            stats = self.engine.run(trace, eos_id=eos_id)
+        stats = self.engine.run(trace, eos_id=eos_id)
         lat = [
             r.t_done - r.t_submit
             for r in trace

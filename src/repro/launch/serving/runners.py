@@ -100,12 +100,15 @@ class PrefillRunner:
         toks = toks.at[0, :plen].set(jnp.asarray(context, jnp.int32))
         lengths = jnp.full((1,), plen, jnp.int32)
         with serving_phase(self.phase):
-            with obs.span("serve.prefill", tokens=plen, padded=padded):
+            with obs.span("serve.prefill.dispatch"):
                 tok, caches = self._run(params, toks, lengths)
                 pools = self._store(
                     pools, caches, jnp.asarray(pages, jnp.int32)
                 )
-        return int(tok[0]), pools
+        # waits for the prefill only: the store runs on asynchronously
+        with obs.span("serve.prefill.wait"):
+            first = int(tok[0])
+        return first, pools
 
 
 class DecodeRunner:
@@ -126,13 +129,17 @@ class DecodeRunner:
         deq = _deq_fn(quant)
 
         def step(params, pools, block_table, lens, tokens):
-            caches = paged.paged_view(pools, block_table, lens, page_size)
+            # the scopes name the paged-KV ops in the compiled step's
+            # metadata, where a device trace's reader finds them
+            with jax.named_scope("paged_view"):
+                caches = paged.paged_view(pools, block_table, lens, page_size)
             logits, new_caches = api.decode_step(
                 deq(params), cfg, caches, tokens[:, None]
             )
-            pools = paged.scatter_token(
-                pools, new_caches, block_table, lens, page_size
-            )
+            with jax.named_scope("scatter_token"):
+                pools = paged.scatter_token(
+                    pools, new_caches, block_table, lens, page_size
+                )
             tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
             return tok, pools
 

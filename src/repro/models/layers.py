@@ -384,7 +384,8 @@ def attention_apply(
         bidx = jnp.arange(B)
         k_cache = cache["k"].at[bidx, idx].set(k[:, 0])
         v_cache = cache["v"].at[bidx, idx].set(v[:, 0])
-        y = decode_attention(q, k_cache, v_cache, idx + 1)
+        with jax.named_scope("decode_attention"):
+            y = decode_attention(q, k_cache, v_cache, idx + 1)
         new_cache = {"k": k_cache, "v": v_cache, "len": idx + 1}
     else:
         # prefill into an empty cache

@@ -6,9 +6,11 @@ throw away after the fact.  This package keeps them visible, in three
 layers that the rest of the repo reports through:
 
 * ``obs.trace`` — nestable spans (``with span("search.beam"): ...``) with
-  a thread-local stack and Chrome-trace/Perfetto JSON export.  Load the
-  dump at ``chrome://tracing`` / https://ui.perfetto.dev, or summarize it
-  with ``scripts/obs_report.py --trace out.json``.
+  a thread-local stack and Chrome-trace/Perfetto JSON export; a bound
+  profiler annotation (``bind_annotation``) puts them on a profile's
+  host plane too.  Load the dump at ``chrome://tracing`` /
+  https://ui.perfetto.dev, or summarize it with
+  ``scripts/obs_report.py --trace out.json``.
 * ``obs.metrics`` — a process-global registry of counters, gauges and
   exact-value histograms (p50/p99) wired into the pipeline's previously
   unsurfaced counters: autotune/plan-DB hits and misses, capture dispatch
@@ -39,6 +41,8 @@ import os
 __all__ = [
     "enabled",
     "span",
+    "gc_spans",
+    "bind_annotation",
     "complete_event",
     "trace_events",
     "trace_json",
@@ -73,7 +77,9 @@ from .metrics import (  # noqa: E402
     registry,
 )
 from .trace import (  # noqa: E402
+    bind_annotation,
     complete_event,
+    gc_spans,
     span,
     trace_dump,
     trace_events,

@@ -16,24 +16,49 @@ that flatten the event list can still reconstruct the nesting.  Export with
 ``chrome://tracing`` and https://ui.perfetto.dev, and
 ``scripts/obs_report.py --trace`` renders a per-name summary.
 
+A profiler annotation factory bound with ``bind_annotation`` (the serving
+tier binds ``jax.profiler.TraceAnnotation``) is entered by every span under
+the span's name and args, so while a profile is being taken the program's
+spans land on its host plane, on the device planes' clock.  ``gc_spans()``
+records a ``host.gc`` span around each collection of Python's oldest
+generation while its block runs.
+
 With ``REPRO_OBS=0`` ``span()`` returns a shared no-op context manager and
-nothing is recorded (the acquired-lock path is never reached).
+nothing is recorded or entered (the acquired-lock path is never reached).
 """
 
 from __future__ import annotations
 
+import contextlib
+import gc
 import json
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 #: process epoch — all ts values are microseconds since this moment
 _EPOCH = time.perf_counter()
 
-_lock = threading.Lock()
+# re-entrant: a ``host.gc`` span may close inside a collection that ran
+# while this thread held the lock to record another event
+_lock = threading.RLock()
 _events: List[Dict[str, Any]] = []
 _tls = threading.local()
+#: ``factory(name, **args)`` -> context manager each span also enters
+_annotation: Optional[Callable[..., Any]] = None
+
+
+def epoch() -> float:
+    """The ``time.perf_counter()`` reading every event's ``ts`` counts from."""
+    return _EPOCH
+
+
+def bind_annotation(factory: Optional[Callable[..., Any]]) -> None:
+    """Have every span also enter ``factory(name, **args)``, e.g.
+    ``jax.profiler.TraceAnnotation``; ``None`` unbinds."""
+    global _annotation
+    _annotation = factory
 
 
 def _stack() -> List[str]:
@@ -46,7 +71,7 @@ def _stack() -> List[str]:
 class _Span:
     """One timed region; records a Chrome-trace "X" event on exit."""
 
-    __slots__ = ("name", "cat", "args", "_t0", "_depth", "_parent")
+    __slots__ = ("name", "cat", "args", "_t0", "_depth", "_parent", "_ann")
 
     def __init__(self, name: str, cat: str, args: Dict[str, Any]):
         self.name = name
@@ -55,17 +80,23 @@ class _Span:
         self._t0 = 0.0
         self._depth = 0
         self._parent: Optional[str] = None
+        self._ann = None
 
     def __enter__(self) -> "_Span":
         st = _stack()
         self._depth = len(st)
         self._parent = st[-1] if st else None
         st.append(self.name)
+        if _annotation is not None:
+            self._ann = _annotation(self.name, **self.args)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         st = _stack()
         if st and st[-1] == self.name:
             st.pop()
@@ -113,6 +144,35 @@ def span(name: str, cat: str = "repro", **args: Any):
     if not enabled():
         return _NOOP
     return _Span(name, cat, args)
+
+
+@contextlib.contextmanager
+def gc_spans():
+    """Record a ``host.gc`` span around each collection of Python's oldest
+    generation while the block runs, from ``gc.callbacks``; the callback
+    is removed on exit.  Registers nothing when ``REPRO_OBS=0``."""
+    from . import enabled
+
+    if not enabled():
+        yield
+        return
+    open_spans: List[Any] = []
+
+    def hook(phase: str, info: Dict[str, Any]) -> None:
+        if info["generation"] != 2:
+            return
+        if phase == "start":
+            sp = span("host.gc")
+            sp.__enter__()
+            open_spans.append(sp)
+        elif open_spans:
+            open_spans.pop().__exit__(None, None, None)
+
+    gc.callbacks.append(hook)
+    try:
+        yield
+    finally:
+        gc.callbacks.remove(hook)
 
 
 def complete_event(

@@ -115,6 +115,53 @@ def test_span_survives_exception():
     assert obs.trace_events()[-1]["args"]["depth"] == 0
 
 
+def test_bound_annotation_wraps_each_span_and_complete_event_skips_it():
+    from repro.obs import trace as obs_trace
+
+    calls = []
+
+    class Recorder:
+        def __init__(self, name, **args):
+            self.name = name
+            calls.append(("new", name, args))
+
+        def __enter__(self):
+            calls.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            calls.append(("exit", self.name))
+
+    bound = obs_trace._annotation
+    obs.bind_annotation(Recorder)
+    try:
+        with obs.span("outer", spec="matmul"):
+            with obs.span("inner"):
+                pass
+        obs.complete_event("retro", 0.0, 1.0)
+    finally:
+        obs.bind_annotation(bound)
+    assert calls == [("new", "outer", {"spec": "matmul"}),
+                     ("enter", "outer"), ("new", "inner", {}),
+                     ("enter", "inner"), ("exit", "inner"),
+                     ("exit", "outer")]
+    assert [e["name"] for e in obs.trace_events()] == ["inner", "outer",
+                                                       "retro"]
+
+
+def test_epoch_is_the_origin_of_event_times():
+    import time
+
+    from repro.obs import trace as obs_trace
+
+    t0 = time.perf_counter()
+    with obs.span("timed"):
+        pass
+    t1 = time.perf_counter()
+    (ev,) = obs.trace_events()
+    start = obs_trace.epoch() + ev["ts"] * 1e-6
+    assert t0 <= start <= t1
+
+
 # -------------------------------------------------------------- metrics
 
 
@@ -190,6 +237,10 @@ def test_disabled_is_strict_noop(monkeypatch):
     }
     with obs.span("never.span"):
         pass
+    with obs.gc_spans():
+        import gc
+
+        gc.collect()
     assert obs.trace_events() == []
     # flipping the env back re-enables without any reload
     monkeypatch.delenv("REPRO_OBS")

@@ -11,12 +11,14 @@ cache position ``p`` lives at page ``pages[p // page_size]``, offset
 on the host (:class:`PagePool`), so requests of wildly different lengths
 share the pool without fragmentation — the whole point of paging.
 
-The model itself is unchanged: before each decode step the lanes' pages
-are gathered into the dense stacked-cache pytree ``models.api`` already
-consumes (:func:`paged_view`), and the single KV row the step appends is
-scattered back to its physical page (:func:`scatter_token`).  Both are
-pure jax functions traced once per (lanes, max_pages) shape — the block
-table and lengths are runtime data, so page churn never recompiles.
+A decode step reads straight from the pools: each layer of the model's
+``paged_decode_step`` gathers its own lanes' pages by block table
+(``models.layers.read_pages``), and the one KV row per lane and layer
+it appends is written back to its physical page (:func:`scatter_token`).
+The block table and lengths are runtime data, so page churn never
+recompiles.  On the chip's path the serving runners donate the pools,
+so the decode step and the prefill store (:func:`store_prefill`) both
+update them in place.
 
 Physical page 0 is reserved as the *sink*: idle decode lanes point their
 block tables at it, and the garbage KV their dispatches produce lands
@@ -100,70 +102,31 @@ def pool_init(cfg: ModelConfig, n_pages: int, page_size: int) -> Dict:
     return pools
 
 
-def paged_view(pools: Dict, block_table, lens, page_size: int) -> Dict:
-    """Gather each lane's pages into the dense stacked-cache pytree.
+def scatter_token(pools: Dict, rows: Dict, block_table, lens,
+                  page_size: int) -> Dict:
+    """Write each lane's new KV row, of every layer, to its page.
 
-    block_table (lanes, max_pages) int32, lens (lanes,) int32 = number of
-    KV rows present per lane.  The view's tail positions (>= lens) hold
-    whatever the sink/unwritten pages contain; ``decode_attention`` masks
-    ``t >= cache_len`` to exactly zero weight, so they are never read.
+    ``rows`` mirror ``pools`` with (layers, lanes, kv, hd) leaves: the rows
+    a decode step appended at position ``lens`` (the pre-step cache
+    length), which physically lives at page ``block_table[lane, lens //
+    page_size]`` offset ``lens % page_size``.  Idle lanes (lens=0,
+    all-sink tables) write their garbage onto the sink page; duplicate
+    sink indices are resolved arbitrarily, which is fine — nothing reads
+    the sink.
     """
-    lanes, max_pages = block_table.shape
-
-    def view(p):
-        g = p[:, block_table]  # (L, lanes, max_pages, page, kv, hd)
-        return g.reshape(
-            p.shape[0], lanes, max_pages * page_size, p.shape[3], p.shape[4]
-        )
-
-    caches: Dict = {}
-    for seg, kinds in pools.items():
-        caches[seg] = {}
-        for kind, pv in kinds.items():
-            n_layers = pv["k"].shape[0]
-            caches[seg][kind] = {
-                "k": view(pv["k"]),
-                "v": view(pv["v"]),
-                "len": jnp.broadcast_to(
-                    lens[None, :].astype(jnp.int32), (n_layers, lanes)
-                ),
-            }
-    return caches
-
-
-def scatter_token(
-    pools: Dict, new_caches: Dict, block_table, lens, page_size: int
-) -> Dict:
-    """Write the KV row each lane's decode step appended back to its page.
-
-    The step wrote at view position ``lens`` (the pre-step cache length),
-    which physically lives at page ``block_table[lane, lens // page_size]``
-    offset ``lens % page_size``.  Idle lanes (lens=0, all-sink tables)
-    scatter their garbage onto the sink page; duplicate sink indices are
-    resolved arbitrarily, which is fine — nothing reads the sink.
-    """
-    lanes = block_table.shape[0]
-    lane = jnp.arange(lanes)
+    lane = jnp.arange(block_table.shape[0])
     page_of = block_table[lane, lens // page_size]  # (lanes,)
     off = lens % page_size
-
-    def pick(arr):  # (L, lanes, ctx, kv, hd) -> row at lens: (L, lanes, kv, hd)
-        idx = jnp.broadcast_to(
-            lens[None, :, None, None, None].astype(jnp.int32),
-            (arr.shape[0], lanes, 1, arr.shape[3], arr.shape[4]),
-        )
-        return jnp.take_along_axis(arr, idx, axis=2)[:, :, 0]
-
-    out: Dict = {}
-    for seg, kinds in pools.items():
-        out[seg] = {}
-        for kind, pv in kinds.items():
-            nc = new_caches[seg][kind]
-            out[seg][kind] = {
-                "k": pv["k"].at[:, page_of, off].set(pick(nc["k"])),
-                "v": pv["v"].at[:, page_of, off].set(pick(nc["v"])),
+    return {
+        seg: {
+            kind: {
+                name: pv[name].at[:, page_of, off].set(rows[seg][kind][name])
+                for name in ("k", "v")
             }
-    return out
+            for kind, pv in kinds.items()
+        }
+        for seg, kinds in pools.items()
+    }
 
 
 def store_prefill(pools: Dict, caches: Dict, page_ids, page_size: int) -> Dict:

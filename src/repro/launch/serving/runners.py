@@ -19,7 +19,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ... import obs
+from ... import obs, ops
 from ...configs.base import ModelConfig
 from ...models.api import ModelAPI
 from ...obs import log
@@ -41,6 +41,16 @@ def _sweep(phase: str, shapes, *, with_grads: bool, mesh_shape=None) -> int:
     )
     log.info("serve", f"searched {n} {phase}-phase GEMM plan(s) -> {db.path}")
     return n
+
+
+def _in_place() -> bool:
+    """Whether the runners donate the pools, so that the decode step and
+    the prefill store update them in place: on the chip's path, as ``ops``
+    picks it.  Elsewhere each call returns new pools and leaves the
+    caller's valid, so a CPU caller may hand a step's input pools back to
+    the engine (the benchmark's rehearsal of a step that drops its KV
+    writes does)."""
+    return ops._use_pallas()
 
 
 def _deq_fn(quant: Optional[str]):
@@ -80,7 +90,8 @@ class PrefillRunner:
         self._store = jax.jit(
             lambda pools, caches, page_ids: paged.store_prefill(
                 pools, caches, page_ids, page_size
-            )
+            ),
+            donate_argnums=(0,) if _in_place() else (),
         )
 
     def sweep(self, shapes, *, with_grads: bool = True, mesh_shape=None):
@@ -112,10 +123,10 @@ class PrefillRunner:
 
 
 class DecodeRunner:
-    """One continuous-batching decode step over all lanes: gather the
-    block-table pages into the dense cache view, run the model's
-    ``decode_step``, scatter the appended KV row back.  Fixed
-    (lanes, max_pages) shapes — traced exactly once."""
+    """One continuous-batching decode step over all lanes, straight from
+    the pools: each layer reads its lanes' pages by block table, and the
+    one KV row per lane and layer the step appends is written back to its
+    page.  Fixed (lanes, max_pages) shapes — traced exactly once."""
 
     phase = "decode"
 
@@ -130,20 +141,21 @@ class DecodeRunner:
 
         def step(params, pools, block_table, lens, tokens):
             # the scopes name the paged-KV ops in the compiled step's
-            # metadata, where a device trace's reader finds them
-            with jax.named_scope("paged_view"):
-                caches = paged.paged_view(pools, block_table, lens, page_size)
-            logits, new_caches = api.decode_step(
-                deq(params), cfg, caches, tokens[:, None]
+            # metadata, where a device trace's reader finds them: each
+            # layer's page read is ``paged_view`` (models/layers.py)
+            logits, rows = api.paged_decode_step(
+                deq(params), cfg, pools, block_table, lens, tokens[:, None]
             )
             with jax.named_scope("scatter_token"):
                 pools = paged.scatter_token(
-                    pools, new_caches, block_table, lens, page_size
+                    pools, rows, block_table, lens, page_size
                 )
             tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
             return tok, pools
 
-        self._step = jax.jit(step)
+        self._step = jax.jit(
+            step, donate_argnums=(1,) if _in_place() else ()
+        )
 
     def sweep(self, shapes, *, with_grads: bool = False, mesh_shape=None):
         # decode dispatches M = lanes activations regardless of what the

@@ -8,6 +8,8 @@ through this adapter:
     loss = api.loss(params, cfg, batch)            # batch: dict of arrays
     logits, caches = api.prefill(params, cfg, batch, max_len)
     logits, caches = api.decode_step(params, cfg, caches, tokens)
+    logits, rows = api.paged_decode_step(params, cfg, pools, block_table,
+                                         lens, tokens)   # dense, moe
 
 ``batch_spec`` defines the exact input tensors for every (family x shape
 kind), which is also what ``launch.dryrun.input_specs`` materializes as
@@ -36,6 +38,9 @@ class ModelAPI:
     decode_step: Callable    # (params, cfg, caches, tokens) -> (logits, caches)
     cache_init: Callable     # (cfg, batch, max_len) -> caches
     cache_axes: Callable     # (cfg) -> logical axes tree
+    # (params, cfg, pools, block_table, lens, tokens) -> (logits, rows);
+    # the families whose KV cache pages (``launch.serving.paged``)
+    paged_decode_step: Optional[Callable] = None
 
 
 def _lm_api() -> ModelAPI:
@@ -53,6 +58,7 @@ def _lm_api() -> ModelAPI:
         decode_step=transformer.decode_step,
         cache_init=transformer.cache_init,
         cache_axes=transformer.cache_axes,
+        paged_decode_step=transformer.paged_decode_step,
     )
 
 

@@ -351,6 +351,19 @@ def decode_attention(
     return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
+def read_pages(pool: jax.Array, layer, block_table: jax.Array) -> jax.Array:
+    """One layer's KV pages in block-table order, as a dense cache.
+
+    pool (layers, n_pages, page, kv, hd); block_table (B, max_pages) page
+    ids.  One gather indexed by (layer, page) gives a fresh
+    (B, max_pages * page, kv, hd) buffer: logical position ``p`` of lane
+    ``b`` is page ``block_table[b, p // page]``, offset ``p % page``.
+    """
+    g = pool[layer, block_table]  # (B, max_pages, page, kv, hd)
+    B, max_pages, page = g.shape[:3]
+    return g.reshape(B, max_pages * page, *g.shape[3:])
+
+
 def attention_apply(
     params,
     cfg: ModelConfig,
@@ -364,6 +377,11 @@ def attention_apply(
     lengths: Optional[jax.Array] = None,
 ):
     """Returns (y, new_cache).  cache = {k, v, len} for decode.
+
+    A paged cache {k, v, layer, block_table, len} (k and v the stacked
+    pools, see :func:`read_pages`) decodes one token straight from the
+    pages: ``new_cache`` is then just the new {k, v} row per lane, which
+    the caller writes back to its page.
 
     ``lengths`` (B,) marks right-padded prefill: keys past each
     sequence's true length are masked out of the attention and the cache
@@ -379,6 +397,20 @@ def attention_apply(
             kv_lengths=lengths,
         )
         new_cache = None
+    elif "block_table" in cache:
+        idx = cache["len"]  # (B,) rows present = this token's position
+        bidx = jnp.arange(B)
+        k_row = k[:, 0].astype(cache["k"].dtype)
+        v_row = v[:, 0].astype(cache["v"].dtype)
+        # the layer's own view of its pages, with the new row in place
+        with jax.named_scope("paged_view"):
+            k_cache = read_pages(cache["k"], cache["layer"],
+                                 cache["block_table"]).at[bidx, idx].set(k_row)
+            v_cache = read_pages(cache["v"], cache["layer"],
+                                 cache["block_table"]).at[bidx, idx].set(v_row)
+        with jax.named_scope("decode_attention"):
+            y = decode_attention(q, k_cache, v_cache, idx + 1)
+        new_cache = {"k": k_row, "v": v_row}
     elif S == 1:
         idx = cache["len"]  # (B,) current write positions
         bidx = jnp.arange(B)

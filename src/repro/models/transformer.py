@@ -124,18 +124,27 @@ def _run_segments(
     params, cfg: ModelConfig, x, *, positions, caches=None,
     q_block=512, k_block=512, lengths=None,
 ):
-    """caches: same segment structure, stacked; returns (x, new_caches)."""
+    """caches: same segment structure, stacked; returns (x, new_caches).
+
+    A paged segment cache (its leaves the stacked pools, see
+    ``paged_decode_step``) stays out of the scan's ``xs``: each layer gets
+    its index and reads its own pages from the pools."""
     new_caches: Dict = {}
     for si, (pattern, count) in enumerate(segment_plan(cfg)):
         seg = params[f"seg{si}"]
         seg_cache = None if caches is None else caches[f"seg{si}"]
+        paged = seg_cache is not None and "block_table" in next(
+            iter(seg_cache.values()))
 
-        def step(carry, xs, pattern=pattern):
+        def step(carry, xs, pattern=pattern, paged=paged, pools=seg_cache):
             h = carry
             lp, lc = xs
             ncs = {}
             for kind in pattern:
-                c = None if lc is None else lc[kind]
+                if paged:
+                    c = dict(pools[kind], layer=lc)
+                else:
+                    c = None if lc is None else lc[kind]
                 h, nc = _block(
                     lp[kind], cfg, kind, h,
                     positions=positions, cache=c,
@@ -147,7 +156,7 @@ def _run_segments(
 
         if cfg.remat and caches is None:
             step = L.remat(step)
-        xs = (seg, seg_cache)
+        xs = (seg, jnp.arange(count) if paged else seg_cache)
         x, seg_new_cache = lax.scan(step, x, xs)
         new_caches[f"seg{si}"] = seg_new_cache
     return x, (new_caches if caches is not None else None)
@@ -213,6 +222,31 @@ def decode_step(params, cfg: ModelConfig, caches, tokens):
     # the KV cache itself)
     x = L.rmsnorm(params["final_norm"], x[:, -1:], cfg.norm_eps)
     return L.logits(params["embedding"], cfg, x), new_caches
+
+
+def paged_decode_step(params, cfg: ModelConfig, pools, block_table, lens,
+                      tokens):
+    """One-token decode straight from paged KV pools.
+
+    pools mirror ``cache_init``'s structure with {"k", "v"} leaves of
+    (layers, n_pages, page, kv, hd); block_table (B, max_pages) page ids;
+    lens (B,) KV rows present per lane, the position of ``tokens`` (B, 1).
+    Each layer reads its own pages, writes its new row into that
+    transient copy and attends over ``lens + 1`` rows.  Returns (logits,
+    rows): rows mirror pools with (layers, B, kv, hd) leaves, each lane's
+    new K/V row for the caller to write back at position ``lens``.
+    """
+    x = L.embed(params["embedding"], tokens).astype(cfg.param_dtype)
+    caches = {
+        seg: {kind: dict(pv, block_table=block_table, len=lens)
+              for kind, pv in kinds.items()}
+        for seg, kinds in pools.items()
+    }
+    x, rows = _run_segments(
+        params, cfg, x, positions=lens[:, None], caches=caches
+    )
+    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    return L.logits(params["embedding"], cfg, x), rows
 
 
 def _first_cache_len(caches) -> jax.Array:

@@ -42,7 +42,9 @@ from repro.launch.serving import (  # noqa: E402
     ServeRequest,
     synthetic_trace,
 )
-from repro.launch.serving import paged  # noqa: E402
+from repro.launch.serving import paged, runners  # noqa: E402
+from repro.models import layers, transformer  # noqa: E402
+from repro.models.api import get_api  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -162,7 +164,7 @@ class TestScheduler:
 
 
 # --------------------------------------------------------------------------
-# paged gather/scatter mapping
+# paged read/write mapping, and the paged decode step
 # --------------------------------------------------------------------------
 
 
@@ -181,28 +183,26 @@ def test_paged_view_and_scatter_mapping(cfg):
     pools = jax.tree.map(stamp, pools)
     bt = jnp.asarray([[3, 1], [2, 0]], jnp.int32)
     lens = jnp.asarray([3, 1], jnp.int32)
-    caches = paged.paged_view(pools, bt, lens, page_size)
 
-    seg = next(iter(caches))
-    kind = next(iter(caches[seg]))
-    k = caches[seg][kind]["k"]
+    seg = next(iter(pools))
+    kind = next(iter(pools[seg]))
+    k = layers.read_pages(pools[seg][kind]["k"], 0, bt)
     # lane 0's view is page 3 then page 1, in block-table order
     np.testing.assert_array_equal(
-        np.asarray(k[0, 0, :, 0, 0]), [300.0, 301.0, 100.0, 101.0]
+        np.asarray(k[0, :, 0, 0]), [300.0, 301.0, 100.0, 101.0]
     )
     np.testing.assert_array_equal(
-        np.asarray(k[0, 1, :, 0, 0]), [200.0, 201.0, 0.0, 1.0]
+        np.asarray(k[1, :, 0, 0]), [200.0, 201.0, 0.0, 1.0]
     )
-    assert int(caches[seg][kind]["len"][0, 0]) == 3
 
-    # fake a decode step: the new KV row lands at view position lens
-    marked = {}
-    for s, kinds in caches.items():
-        marked[s] = {}
-        for kd, c in kinds.items():
-            nk = c["k"].at[:, 0, 3].set(777.0).at[:, 1, 1].set(888.0)
-            marked[s][kd] = {"k": nk, "v": nk, "len": c["len"] + 1}
-    pools2 = paged.scatter_token(pools, marked, bt, lens, page_size)
+    # the row each lane appended at position lens, for every layer
+    rows = jax.tree.map(
+        lambda p: jnp.broadcast_to(
+            jnp.asarray([777.0, 888.0], p.dtype)[None, :, None, None],
+            (p.shape[0], 2) + p.shape[3:]),
+        pools,
+    )
+    pools2 = paged.scatter_token(pools, rows, bt, lens, page_size)
     k2 = pools2[seg][kind]["k"]
     # lane 0: position 3 -> page bt[0, 1]=1, offset 1
     assert float(k2[0, 1, 1, 0, 0]) == 777.0
@@ -210,6 +210,131 @@ def test_paged_view_and_scatter_mapping(cfg):
     assert float(k2[0, 2, 1, 0, 0]) == 888.0
     # untouched slots keep their stamp
     assert float(k2[0, 3, 0, 0, 0]) == 300.0
+
+
+PS, MAX_PAGES, LANES = 4, 4, 4     # max_ctx 16, pages 1..16 for the lanes
+
+#: (lens, live) per lane; a lane that is not live points at the sink
+DECODE_CASES = {
+    # lane 0 starts a fresh page
+    "page_boundary": ([8, 5, 0, 3], [True, True, False, True]),
+    # lane 1 writes the last position of its last page
+    "max_ctx_minus_1": ([6, 15, 1, 0], [True, True, True, False]),
+    # three idle lanes write their garbage onto the sink together
+    "idle_sink": ([0, 6, 0, 0], [False, True, False, False]),
+}
+
+
+def _decode_inputs(cfg, case, seed=0):
+    lens, live = DECODE_CASES[case]
+    rng = np.random.default_rng(seed)
+    pages = rng.permutation(np.arange(1, 1 + LANES * MAX_PAGES))
+    bt = np.zeros((LANES, MAX_PAGES), np.int32)
+    toks = np.zeros((LANES,), np.int32)
+    for lane, (n, on) in enumerate(zip(lens, live)):
+        if on:
+            need = n // PS + 1      # the page the step writes is allocated
+            bt[lane, :need] = pages[lane * MAX_PAGES:lane * MAX_PAGES + need]
+            toks[lane] = rng.integers(1, cfg.vocab)
+    pools = paged.pool_init(cfg, 1 + LANES * MAX_PAGES, PS)
+    keys = iter(jax.random.split(jax.random.key(seed),
+                                 len(jax.tree.leaves(pools))))
+    pools = jax.tree.map(
+        lambda p: jax.random.normal(next(keys), p.shape).astype(p.dtype), pools
+    )
+    return pools, jnp.asarray(bt), jnp.asarray(lens, jnp.int32), jnp.asarray(toks)
+
+
+def _dense_composition(params, cfg, pools, bt, lens, toks):
+    """The decode step as gather -> ``decode_step`` -> scatter: every
+    layer's pages gathered into a dense cache, the model's dense-cache
+    decode, and each lane's new row picked out of the new cache."""
+    lanes, max_pages = bt.shape
+    caches = jax.tree.map(
+        lambda p: p[:, bt].reshape(p.shape[0], lanes, max_pages * p.shape[2],
+                                   *p.shape[3:]), pools)
+    for kinds in caches.values():
+        for c in kinds.values():
+            c["len"] = jnp.broadcast_to(
+                lens, (c["k"].shape[0], lanes)).astype(jnp.int32)
+    logits, new = transformer.decode_step(params, cfg, caches, toks[:, None])
+    lane = jnp.arange(lanes)
+    page_of, off = bt[lane, lens // PS], lens % PS
+    out = {
+        seg: {kind: {n: pv[n].at[:, page_of, off].set(
+            new[seg][kind][n][:, lane, lens]) for n in ("k", "v")}
+            for kind, pv in kinds.items()}
+        for seg, kinds in pools.items()
+    }
+    return logits, out
+
+
+@pytest.fixture(scope="module", params=["dense", "moe-interleaved",
+                                        "moe-segments"])
+def decode_model(request):
+    arch = {"dense": "qwen3-8b", "moe-interleaved": "llama4-maverick-400b-a17b",
+            "moe-segments": "kimi-k2-1t-a32b"}[request.param]
+    c = get_config(arch).smoke()
+    params, _ = transformer.init(c, jax.random.key(1))
+    return c, params
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_CASES))
+def test_paged_decode_step_equals_the_dense_composition(
+        decode_model, case, monkeypatch):
+    """One decode step straight from the pages gives the logits and
+    tokens of gather -> dense decode -> scatter, and bit-identical pages,
+    through the model's entry and through the runner's donated step."""
+    c, params = decode_model
+    pools, bt, lens, toks = _decode_inputs(c, case)
+    want_logits, want_pools = _dense_composition(params, c, pools, bt, lens,
+                                                 toks)
+
+    logits, rows = transformer.paged_decode_step(params, c, pools, bt, lens,
+                                                 toks[:, None])
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want_logits))
+    got = paged.scatter_token(pools, rows, bt, lens, PS)
+    jax.tree.map(np.testing.assert_array_equal, got, want_pools)
+
+    monkeypatch.setattr(runners, "_in_place", lambda: True)
+    runner = runners.DecodeRunner(c, get_api(c), PS, LANES, MAX_PAGES)
+    tok, got = runner(params, jax.tree.map(jnp.copy, pools), bt, lens, toks)
+    np.testing.assert_array_equal(
+        np.asarray(tok), np.asarray(jnp.argmax(want_logits[:, -1], axis=-1)))
+    jax.tree.map(np.testing.assert_array_equal, got, want_pools)
+
+
+def test_the_decode_step_donates_the_pools_and_builds_no_dense_view(
+        cfg, monkeypatch):
+    """The step the chip runs, lowered: every pool leaf is donated, no
+    array has the all-layer view's shape, and the only outputs are the
+    tokens and the pools.  The prefill store donates the pools too."""
+    monkeypatch.setattr(runners, "_in_place", lambda: True)
+    lanes, max_pages, ps = 3, 5, 4
+    pools = paged.pool_init(cfg, 1 + lanes * max_pages, ps)
+    params, _ = transformer.init(cfg, jax.random.key(0))
+    api = get_api(cfg)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    step = runners.DecodeRunner(cfg, api, ps, lanes, max_pages)._step.lower(
+        params, pools, i32(lanes, max_pages), i32(lanes), i32(lanes))
+
+    n_pools = len(jax.tree.leaves(pools))
+    donated = jax.tree.leaves(step.args_info[0][1],
+                              is_leaf=lambda a: hasattr(a, "donated"))
+    assert len(donated) == n_pools and all(a.donated for a in donated)
+    text = step.as_text()
+    assert text.count("tf.aliasing_output") == n_pools
+    L = cfg.n_layers
+    view = f"tensor<{L}x{lanes}x{max_pages * ps}x{cfg.n_kv_heads}x{cfg.hd}x"
+    assert view not in text
+    outs = [tuple(o.shape) for o in jax.tree.leaves(step.out_info)]
+    assert sorted(outs) == sorted([(lanes,)] + [
+        p.shape for p in jax.tree.leaves(pools)])
+
+    pre = runners.PrefillRunner(cfg, api, ps)
+    caches = transformer.cache_init(cfg, 1, 2 * ps)
+    store = pre._store.lower(pools, caches, i32(2))
+    assert store.as_text().count("tf.aliasing_output") == n_pools
 
 
 # --------------------------------------------------------------------------
